@@ -45,7 +45,6 @@ import numpy as np
 
 from benchmarks.common import csv_row, time_call
 from repro.analysis import jaxpr_audit
-from repro.compat import cost_analysis_dict
 from repro.core.features import sketch_svd_features, svd_features
 from repro.core.maxvol import fast_maxvol
 from repro.core.projection import prefix_projection_errors
@@ -64,7 +63,7 @@ _MIN_FLOPS_RATIO = 5.0                   # sketch_svd must beat svd by this
 
 def _flops(fn, *args) -> float:
     compiled = jax.jit(fn).lower(*args).compile()
-    return cost_analysis_dict(compiled).get("flops", 0.0)
+    return compiled.cost_analysis().get("flops", 0.0)
 
 
 # jaxpr walking lives in repro.analysis.jaxpr_audit — one implementation

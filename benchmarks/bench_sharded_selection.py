@@ -28,6 +28,7 @@ import numpy as np
 
 from benchmarks.common import csv_row, time_call
 from repro.distributed import sharding as sh
+from repro.launch.mesh import make_mesh
 from repro.selection import GraftConfig, engine
 
 
@@ -59,7 +60,7 @@ def run() -> List[str]:
                         f"per_batch_us={t_vmap / n:.1f}"))
 
     # shard_map data-parallel: n shards of K_local rows each
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh((n, 1), ("data", "model"))
     Vg, Gg, _ = batch(n * K_local)
     Vg = jax.device_put(Vg, sh.named_sharding(mesh, ("act_batch", None)))
     Gg = jax.device_put(Gg, sh.named_sharding(mesh, (None, "act_batch")))

@@ -3,9 +3,11 @@
 Reads experiments/dryrun/*.json (written by repro.launch.dryrun), derives the
 three roofline terms per (arch × shape × variant) on the single-pod mesh:
 
-    T_compute = HLO_FLOPs   / (chips · 197e12 FLOP/s bf16)
-    T_memory  = HLO_bytes   / (chips · 819e9 B/s HBM)
-    T_coll    = coll_bytes  / (chips · 50e9 B/s ICI link)
+    T_compute = HLO_FLOPs   / (chips · peak bf16 FLOP/s)
+    T_memory  = HLO_bytes   / (chips · peak HBM B/s)
+    T_coll    = coll_bytes  / (chips · peak ICI B/s)
+
+with the TPU v5e peaks of ``repro.launch.metrics.DEVICE_PEAKS``.
 
 FLOPs/bytes/coll_bytes use the L=p vs L=2p unrolled deltas scaled to full
 depth (scan bodies are counted once by XLA cost analysis — see dryrun.py).
@@ -18,9 +20,13 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
-ICI_BW = 50e9                # B/s / link (conservative single-link figure)
+from repro.launch.metrics import DEVICE_PEAKS
+
+# the dry run models TPU v5e pods; peaks come from the one device table
+_PEAKS = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _PEAKS["bf16_flops"]
+HBM_BW = _PEAKS["hbm_bytes_per_s"]
+ICI_BW = _PEAKS["ici_bytes_per_s"]
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
 

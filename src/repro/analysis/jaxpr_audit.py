@@ -28,6 +28,7 @@ from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional, \
     Sequence, Tuple
 
 import jax
+from jax.extend import core as jex_core
 
 from repro.analysis.report import Finding, Report
 
@@ -46,9 +47,9 @@ _WIDE_DTYPES = ("float64", "complex128")
 # ---------------------------------------------------------------------------
 
 def _subjaxprs(v) -> Iterator[Any]:
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for item in v:

@@ -120,16 +120,15 @@ def flash_feasible(S: int, T: int, head_dim: int,
 
 def fused_select_vmem(K: int, R: int, d: int, rank: int,
                       itemsize: int = 4) -> VmemEstimate:
-    """Fused GRAFT selection, single program: V (K,R), G (d,K), the
-    selected-columns output G_sel (d,rank), the MGS basis Q (d,rank), and
-    the K×rank one-hot — matching ``graft_select.py:_check_budget``'s
-    ``words = K*R + d*K + 2*d*rank + K*rank``."""
+    """Fused GRAFT selection, single program: V (K,R) and the MaxVol
+    residual W (K,R), G (d,K), the selected-columns output G_sel (d,rank)
+    and the MGS basis Q (d,rank) — the ``words = 2*K*R + d*K + 2*d*rank``
+    that ``graft_select.py:_check_budget`` enforces."""
     return VmemEstimate(
         kernel=f"graft_select(K={K}, R={R}, d={d}, rank={rank})",
-        parts={"V": K * R * itemsize,
+        parts={"V+W": 2 * K * R * itemsize,
                "G": d * K * itemsize,
-               "G_sel+Q": 2 * d * rank * itemsize,
-               "one_hot": K * rank * itemsize})
+               "G_sel+Q": 2 * d * rank * itemsize})
 
 
 def fast_maxvol_vmem(K: int, R: int, itemsize: int = 4) -> VmemEstimate:

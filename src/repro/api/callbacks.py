@@ -29,13 +29,15 @@ from __future__ import annotations
 import math
 from typing import Any, Callable, Dict, Optional
 
+import jax
+
 from repro.analysis.sync_guard import sync_allowed
 from repro.checkpoint import CheckpointManager, EmergencySaver
 from repro.distributed.straggler import StragglerMonitor
 from repro.launch.evaluate import make_eval_fn_for
 from repro.launch.metrics import (MetricsLogger, format_step_line,
-                                  materialize_metrics, sanitize_row,
-                                  train_step_flops)
+                                  materialize_metrics, peak_flops,
+                                  sanitize_row, train_step_flops)
 from repro.selection.overlap import SideStream
 
 
@@ -163,7 +165,8 @@ class MetricsCallback(Callback):
                 remat=trainer.mcfg.remat != "none",
                 mcfg=trainer.mcfg, seq=tr.seq),
             flush_every=self.flush_every,
-            device_clock=trainer.device_clock)
+            device_clock=trainer.device_clock,
+            peak_flops_per_chip=peak_flops(jax.devices()[0].device_kind))
 
     def on_step_end(self, trainer, step, metrics) -> None:
         tr = trainer.config.train

@@ -18,11 +18,30 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 from typing import List, Optional
 
 from repro.api.config import ExperimentConfig
 from repro.api.trainer import Trainer
+
+
+# <checkout>/.jax_cache: a fixed path, since the path is part of the
+# persistent cache's key and a moving directory never hits
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else at the checkout's
+    ``.jax_cache``. Only entry points call this; library code sets no
+    cache. Returns the directory."""
+    import jax
+    path = os.path.normpath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                            or _DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _split_args(argv: List[str]):

@@ -1,19 +1,19 @@
 """The single-process backend — today's semantics, bit-identical.
 
-Every primitive here is exactly what the pre-backend ``Trainer`` hardwired:
-the mesh is ``launch.mesh.make_host_mesh()`` (all local devices, 1-D
-``data`` axis), ``shard_batch`` is ``jnp.asarray`` per array, ``replicate``
-is the identity, and there is no distributed runtime to bring up. The
-bit-identity regression test (``tests/test_backend.py``) pins this against
-a hand-rolled pre-backend loop — trajectory and graft pivots both.
+The mesh is ``launch.mesh.make_host_mesh()`` (all local devices, 1-D
+``data`` axis); ``shard_batch`` lays each batch's leading dim out along
+that axis, so on N devices each holds B/N rows (on one device it is a plain
+transfer); ``replicate`` is the identity, and there is no distributed
+runtime to bring up. The bit-identity regression test
+(``tests/test_backend.py``) pins the one-device loop against a hand-rolled
+pre-backend loop — trajectory and graft pivots both.
 """
 from __future__ import annotations
 
 from typing import Any, Dict
 
-import jax.numpy as jnp
-
 from repro.backend import base
+from repro.distributed.pipeline import assemble_global_batch
 from repro.launch.mesh import make_host_mesh
 
 
@@ -24,7 +24,7 @@ class LocalBackend(base.Backend):
         return make_host_mesh()
 
     def shard_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
-        return {k: jnp.asarray(v) for k, v in batch.items()}
+        return assemble_global_batch(self.mesh(), batch)
 
 
 def _build(cfg: base.LocalBackendConfig) -> LocalBackend:

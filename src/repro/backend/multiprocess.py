@@ -90,8 +90,8 @@ class MultiProcessBackend(base.Backend):
 
     # -------------------------------- devices -------------------------------
     def _build_mesh(self):
-        import jax
-        return jax.make_mesh((self.device_count(),), ("data",))
+        from repro.launch.mesh import make_mesh
+        return make_mesh((self.device_count(),), ("data",))
 
     def shard_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         from repro.distributed.pipeline import assemble_global_batch
@@ -140,7 +140,7 @@ class MultiProcessBackend(base.Backend):
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
 
         mesh = self.mesh()
         leaves, treedef = jax.tree_util.tree_flatten(tree)

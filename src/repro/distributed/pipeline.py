@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import pvary, shard_map
+from jax import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -132,8 +132,10 @@ def pipeline_forward(layer_fn: Callable, stage_params, x_micro: jax.Array,
         stage = jax.lax.axis_index(axis)
         p_local = jax.tree_util.tree_map(lambda a: a[0], params_s)
         # carries are device-varying (they hold per-stage state) — mark them
-        buf = pvary(jnp.zeros_like(x_all[0]), (axis,))    # (mb, …)
-        outs = pvary(jnp.zeros_like(x_all), (axis,))      # (M, mb, …)
+        buf = jax.lax.pcast(jnp.zeros_like(x_all[0]), axis,
+                            to="varying")                 # (mb, …)
+        outs = jax.lax.pcast(jnp.zeros_like(x_all), axis,
+                             to="varying")                # (M, mb, …)
 
         def tick(t, carry):
             buf, outs = carry

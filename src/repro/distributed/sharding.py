@@ -151,6 +151,34 @@ def constrain(x: jax.Array, logical: Sequence[Optional[str]]) -> jax.Array:
         x, NamedSharding(_STATE.mesh, spec))
 
 
+def shard_kernel(fn, args: Sequence[jax.Array],
+                 in_logical: Sequence[Sequence[Optional[str]]],
+                 out_logical: Sequence[Optional[str]]):
+    """``fn(*args)`` for a function that runs Pallas kernels.
+
+    XLA cannot partition a Mosaic kernel, so under a multi-device mesh the
+    call runs in a ``shard_map`` over the whole mesh: each device runs the
+    kernel on its shard of the logical axes, and replicated operands are
+    computed redundantly. ``out_logical`` names the axes of every output.
+    Without a mesh, on one device, or already inside a ``shard_map``, this
+    is the plain call.
+    """
+    mesh = _STATE.mesh if _STATE.enabled else None
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return fn(*args)
+
+    def spec(logical, shape):
+        return drop_indivisible(logical_to_spec(logical), shape, mesh)
+
+    out_shapes = jax.eval_shape(fn, *args)
+    out_specs = jax.tree_util.tree_map(lambda s: spec(out_logical, s.shape),
+                                       out_shapes)
+    in_specs = tuple(spec(lg, a.shape) for a, lg in zip(args, in_logical))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
 def named_sharding(mesh: Mesh, logical: Sequence[Optional[str]],
                    rules: Optional[Dict[str, MeshAxes]] = None) -> NamedSharding:
     return NamedSharding(mesh, logical_to_spec(logical, mesh, rules))

@@ -3,12 +3,12 @@
 TPU adaptation (DESIGN.md §3): the K×R feature matrix is tiny (K ≤ 1024,
 R ≤ 128 ⇒ ≤ 512 KB fp32), so the WHOLE matrix lives in VMEM for the entire
 R-step pivot loop — zero HBM round trips between steps, unlike the GPU
-implementation's per-step kernel launches. Each step is a VPU-aligned
-K-vector scan (argmax) + rank-1 FMA update; K is padded to the 8×128 lane
-grid by the wrapper in ``ops.py``.
+implementation's per-step kernel launches. Each step is a K-vector scan
+(argmax) + rank-1 FMA update on the VPU; the loop is
+``graft_select.maxvol_loop``, shared with the fused kernel.
 
-Grid: (1,) — selection is inherently sequential in R; parallelism is across
-the K rows inside each step (lane dimension).
+Grid: () — selection is inherently sequential in R; parallelism is across
+the K rows inside each step.
 """
 from __future__ import annotations
 
@@ -18,37 +18,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.numerics import safe_pivot
+from repro.kernels.graft_select import maxvol_loop
 
 
 def _fast_maxvol_kernel(v_ref, pivots_ref, logvol_ref, *, rank: int):
     """One invocation selects all ``rank`` pivots.
 
-    v_ref:      (K, R) f32 VMEM — feature matrix (mutated in place as the
-                residual matrix; Pallas gives us a private copy).
-    pivots_ref: (rank,) i32 VMEM out.
-    logvol_ref: (1,) f32 VMEM out — accumulated log|det|.
+    v_ref:      (K, R) f32 VMEM — feature matrix.
+    pivots_ref: (1, rank) i32 VMEM out.
+    logvol_ref: (1, 1) f32 VMEM out — accumulated log|det|.
     """
-    K = v_ref.shape[0]
-    W0 = v_ref[...]                                    # load full matrix to registers/VMEM
-    avail0 = jnp.ones((K,), dtype=jnp.float32)
-
-    def body(j, carry):
-        W, avail, logvol = carry
-        col = W[:, j]
-        scores = jnp.where(avail > 0, jnp.abs(col), -1.0)
-        pj = jnp.argmax(scores)
-        pivot_val = safe_pivot(W[pj, j])
-        factor = col / pivot_val                       # (K,)
-        pivot_row = W[pj, :]                           # (R,)
-        W_new = W - factor[:, None] * pivot_row[None, :]
-        W_new = jnp.where((jax.lax.iota(jnp.int32, K) == pj)[:, None], W, W_new)
-        avail = jnp.where(jax.lax.iota(jnp.int32, K) == pj, 0.0, avail)
-        pivots_ref[j] = pj.astype(jnp.int32)
-        return W_new, avail, logvol + jnp.log(jnp.abs(pivot_val))
-
-    _, _, logvol = jax.lax.fori_loop(0, rank, body, (W0, avail0, jnp.float32(0.0)))
-    logvol_ref[0] = logvol
+    pivots, logvol = maxvol_loop(v_ref[...], rank)
+    pivots_ref[...] = pivots.astype(jnp.int32)
+    logvol_ref[...] = logvol
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "interpret"))
@@ -66,12 +48,13 @@ def fast_maxvol_pallas(V: jax.Array, rank: int, interpret: bool = False):
     kernel = functools.partial(_fast_maxvol_kernel, rank=rank)
     pivots, logvol = pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((rank,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((1, rank), jnp.int32),
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)),
         in_specs=[pl.BlockSpec((K, R), lambda: (0, 0))],
-        out_specs=(pl.BlockSpec((rank,), lambda: (0,)),
-                   pl.BlockSpec((1,), lambda: (0,))),
+        out_specs=(pl.BlockSpec((1, rank), lambda: (0, 0)),
+                   pl.BlockSpec((1, 1), lambda: (0, 0))),
         grid=(),
         interpret=interpret,
+        name="fast_maxvol",
     )(V.astype(jnp.float32))
-    return pivots, logvol[0]
+    return pivots[0], logvol[0, 0]

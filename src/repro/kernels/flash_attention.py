@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Mask fill value. A tile whose mask is ALL false leaves the running max at
 # this sentinel; the online-softmax update must then suppress its
@@ -85,8 +86,8 @@ def _kv_bounds(q_offset, BQ: int, block_k: int, n_tiles: int, causal: bool,
 def _flash_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref, *, block_k: int,
                   causal: bool, use_window: bool, softcap: Optional[float],
                   scale: float, bound_loop: bool):
-    """Blocks: q (1, BQ, Dh); k/v (1, T, Dh); w (1,); o (1, BQ, Dh);
-    lse (1, BQ) f32."""
+    """Blocks: q (1, BQ, Dh); k/v (1, T, Dh); w (1,) in SMEM;
+    o (1, BQ, Dh); lse (1, 1, BQ) f32."""
     qs = q_ref[0].astype(jnp.float32) * scale          # (BQ, Dh)
     BQ = qs.shape[0]
     T = k_ref.shape[1]
@@ -120,7 +121,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref, *, block_k: int,
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     # logsumexp residual for the backward recompute; +inf marks rows whose
     # whole horizon is masked (output 0), so bwd p = exp(s - lse) = 0 there.
-    lse_ref[0] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
+    lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
+    lse_ref[0] = lse[None, :]
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, w_ref,
@@ -129,8 +131,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, w_ref,
     """dQ over the same (BH, Sq//BQ) grid / KV loop as the forward."""
     qs = q_ref[0].astype(jnp.float32) * scale
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                    # (BQ,)
-    delta = delta_ref[0]                                # (BQ,)
+    lse = lse_ref[0].T                                  # (BQ, 1)
+    delta = delta_ref[0].T                              # (BQ, 1)
     BQ = qs.shape[0]
     T = k_ref.shape[1]
     q_offset = pl.program_id(1) * BQ
@@ -142,9 +144,9 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, w_ref,
         mask = _tile_mask(q_offset, i * block_k, BQ, block_k,
                           causal, use_window, w)
         s = _tile_scores(qs, k, mask, softcap)
-        p = jnp.exp(s - lse[:, None])                   # normalized; 0 if masked
+        p = jnp.exp(s - lse)                            # normalized; 0 if masked
         dp = do @ v.T
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         if softcap is not None:
             t = s / softcap                             # tanh(s_raw/cap) where unmasked
             ds = ds * jnp.where(mask, 1.0 - t * t, 0.0)
@@ -162,7 +164,7 @@ def _flash_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, w_ref,
                       group: int, bound_loop: bool):
     """dK/dV over the (B·Hkv, T//BK) grid; loops q tiles, unrolls the GQA
     group (each kv stream serves ``group`` q streams). Blocks: q/do
-    (group, Sq, Dh); lse/delta (group, Sq); k/v/dk/dv (1, BK, Dh)."""
+    (group, Sq, Dh); lse/delta (group, 1, Sq); k/v/dk/dv (1, BK, Dh)."""
     k = k_ref[0].astype(jnp.float32)                    # (BK, Dh)
     v = v_ref[0].astype(jnp.float32)
     BK = k.shape[0]
@@ -190,14 +192,14 @@ def _flash_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, w_ref,
                 jnp.float32) * scale
             do = do_ref[g, pl.dslice(iq * block_q, block_q), :].astype(
                 jnp.float32)
-            lse = lse_ref[g, pl.dslice(iq * block_q, block_q)]
-            delta = delta_ref[g, pl.dslice(iq * block_q, block_q)]
+            lse = lse_ref[g, :, pl.dslice(iq * block_q, block_q)].T
+            delta = delta_ref[g, :, pl.dslice(iq * block_q, block_q)].T
             mask = _tile_mask(iq * block_q, k_offset, block_q, BK,
                               causal, use_window, w)
             s = _tile_scores(qs, k, mask, softcap)
-            p = jnp.exp(s - lse[:, None])
+            p = jnp.exp(s - lse)
             dp = do @ v.T
-            ds = p * (dp - delta[:, None])
+            ds = p * (dp - delta)
             if softcap is not None:
                 t = s / softcap
                 ds = ds * jnp.where(mask, 1.0 - t * t, 0.0)
@@ -205,6 +207,18 @@ def _flash_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, w_ref,
         dk, dv = jax.lax.fori_loop(lo, hi, body, (dk, dv))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+# Per-row residuals (lse, delta) live as (B·H, 1, Sq): a (1, 1, block_q)
+# block keeps the last two block dims legal for the TPU lowering ((1, Sq)
+# is the array's own size on the sublane axis, block_q a lane multiple),
+# where a (1, block_q) block of a (B·H, Sq) array is refused. The window
+# scalar rides in SMEM, where the kernel can read it as a scalar.
+_W_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _row_spec(block_q: int):
+    return pl.BlockSpec((1, 1, block_q), lambda bh, iq: (bh, 0, iq))
 
 
 def _forward(q, k, v, w, *, block_q, block_k, causal, use_window, softcap,
@@ -218,14 +232,14 @@ def _forward(q, k, v, w, *, block_q, block_k, causal, use_window, softcap,
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32)),
+                   jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)),
         in_specs=[pl.BlockSpec((1, block_q, Dh), lambda bh, iq: (bh, iq, 0)),
-                  kv_spec, kv_spec,
-                  pl.BlockSpec((1,), lambda bh, iq: (0,))],
+                  kv_spec, kv_spec, _W_SPEC],
         out_specs=(pl.BlockSpec((1, block_q, Dh), lambda bh, iq: (bh, iq, 0)),
-                   pl.BlockSpec((1, block_q), lambda bh, iq: (bh, iq))),
+                   _row_spec(block_q)),
         grid=(BH, Sq // block_q),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v, w)
 
 
@@ -233,7 +247,8 @@ def _backward(q, k, v, w, o, lse, do, *, block_q, block_k, causal, use_window,
               softcap, scale, group, bound_loop, interpret):
     BH, Sq, Dh = q.shape
     BHkv, T = k.shape[0], k.shape[1]
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
+                    keepdims=True).transpose(0, 2, 1)             # (BH, 1, Sq)
 
     dq_kernel = functools.partial(
         _flash_dq_kernel, block_k=block_k, causal=causal,
@@ -241,15 +256,16 @@ def _backward(q, k, v, w, o, lse, do, *, block_q, block_k, causal, use_window,
         bound_loop=bound_loop)
     kv_spec = pl.BlockSpec((1, T, Dh), lambda bh, iq: (bh // group, 0, 0))
     q_spec = pl.BlockSpec((1, block_q, Dh), lambda bh, iq: (bh, iq, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda bh, iq: (bh, iq))
+    row_spec = _row_spec(block_q)
     dq = pl.pallas_call(
         dq_kernel,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
-                  pl.BlockSpec((1,), lambda bh, iq: (0,))],
+                  _W_SPEC],
         out_specs=q_spec,
         grid=(BH, Sq // block_q),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta, w)
 
     dkv_kernel = functools.partial(
@@ -257,17 +273,18 @@ def _backward(q, k, v, w, o, lse, do, *, block_q, block_k, causal, use_window,
         use_window=use_window, softcap=softcap, scale=scale, group=group,
         bound_loop=bound_loop)
     g_spec = pl.BlockSpec((group, Sq, Dh), lambda bkv, jk: (bkv, 0, 0))
-    grow_spec = pl.BlockSpec((group, Sq), lambda bkv, jk: (bkv, 0))
+    grow_spec = pl.BlockSpec((group, 1, Sq), lambda bkv, jk: (bkv, 0, 0))
     k_spec = pl.BlockSpec((1, block_k, Dh), lambda bkv, jk: (bkv, jk, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
         out_shape=(jax.ShapeDtypeStruct((BHkv, T, Dh), k.dtype),
                    jax.ShapeDtypeStruct((BHkv, T, Dh), v.dtype)),
         in_specs=[g_spec, g_spec, grow_spec, grow_spec, k_spec, k_spec,
-                  pl.BlockSpec((1,), lambda bkv, jk: (0,))],
+                  _W_SPEC],
         out_specs=(k_spec, k_spec),
         grid=(BHkv, T // block_k),
         interpret=interpret,
+        name="flash_dkv",
     )(q, do, lse, delta, k, v, w)
     return dq, dk, dv
 

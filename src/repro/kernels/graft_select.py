@@ -6,21 +6,21 @@ G) → ``projection_sweep`` (prefix errors). Here all three run in a single
 ``pallas_call`` with ``V (K, R)`` and ``G (d, K)`` resident in VMEM for the
 whole refresh:
 
-  1. Fast MaxVol pivot loop on V — identical control flow to
-     ``kernels/fast_maxvol.py`` (same ``safe_pivot`` guard, same tie-break),
-     so pivots are bit-identical to the unfused kernel.
-  2. Column gather ``G_sel = G @ onehot(pivots)`` — a one-hot matmul rather
-     than a dynamic gather: exact (one 1.0 per column) and MXU-friendly.
-  3. MGS prefix projection-error sweep over ``G_sel`` against ``ḡ`` —
-     identical arithmetic to ``kernels/projection_sweep.py``.
+  1. Fast MaxVol pivot loop on V (``maxvol_loop``) — the arithmetic of
+     ``core.maxvol.fast_maxvol``, so pivots are bit-identical to it.
+  2. Column gather ``G_sel = G[:, pivots]`` (``gather_columns``) — exact.
+  3. MGS prefix projection-error sweep over ``G_sel`` against ``ḡ``
+     (``mgs_errors``).
 
-Two variants share one body:
+The standalone ``fast_maxvol`` and ``projection_sweep`` kernels run these
+same loop functions, so the fused and unfused kernel chains agree bit for
+bit. Both entry points launch one ``grid=(B,)`` kernel:
 
-  * ``fused_graft_select_pallas``          — ``grid=()``, one (K, R) batch.
-  * ``fused_graft_select_batched_pallas``  — ``grid=(B,)``, a whole
-    microbatch stack in ONE kernel launch (each grid step owns one batch's
-    VMEM blocks). This is what ``engine.select_multi_batch`` dispatches
-    instead of vmapping the ``grid=()`` kernel, which Mosaic cannot lower.
+  * ``fused_graft_select_pallas``          — one (K, R) batch, B = 1.
+  * ``fused_graft_select_batched_pallas``  — a whole microbatch stack in
+    ONE kernel launch (each grid step owns one batch's VMEM blocks). This
+    is what ``engine.select_multi_batch`` dispatches instead of vmapping
+    the single-batch kernel.
 """
 from __future__ import annotations
 
@@ -32,97 +32,133 @@ from jax.experimental import pallas as pl
 
 from repro.core.numerics import safe_pivot
 
-# MGS guard — must match kernels/projection_sweep.py for bit-identical errors
+# MGS guard, as in core/projection.py
 _EPS = 1e-12
 
-# single-core VMEM budget for all resident blocks (f32 words, bytes)
-# re-exported from the shared estimator (see repro.analysis.vmem, which
-# owns the budget and the per-kernel footprint formulas)
-from repro.analysis.vmem import VMEM_BUDGET_BYTES as _VMEM_BUDGET_BYTES
+
+def _iota(shape, dim: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+# Mosaic lowers no dynamic slice of a loaded value, so every per-step
+# column, row or element read below is a masked reduction: ``where`` keeps
+# exactly one nonzero per output and the sum of one value and zeros is
+# that value, so the reads are exact. Vectors stay 2-D ((K, 1) columns,
+# (1, R) rows, (1, 1) scalars), as the TPU lowering wants, and row indices
+# ride as f32 next to the data (exact below 2**24).
+
+def maxvol_loop(V: jax.Array, rank: int):
+    """Fast MaxVol on a loaded (K, R) f32 value — the arithmetic of
+    ``core.maxvol.fast_maxvol`` (same ``safe_pivot`` guard, same
+    first-index tie-break), so pivots are bit-identical to it.
+
+    Returns (pivots (1, rank) f32 row indices, logvol (1, 1) f32).
+    """
+    K, R = V.shape
+    rows = _iota((K, 1), 0).astype(jnp.float32)
+    cols = _iota((1, R), 1)
+    slots = _iota((1, rank), 1)
+
+    def body(j, carry):
+        W, avail, pivots, logvol = carry
+        col = jnp.sum(jnp.where(cols == j, W, 0.0), axis=1,
+                      keepdims=True)                    # W[:, j]   (K, 1)
+        scores = jnp.where(avail > 0, jnp.abs(col), -1.0)
+        best = jnp.max(scores, axis=0, keepdims=True)
+        # argmax: the first row attaining the max
+        pj = jnp.min(jnp.where(scores == best, rows, float(K)), axis=0,
+                     keepdims=True)                     # (1, 1)
+        is_pivot = rows == pj                           # (K, 1)
+        pivot_val = safe_pivot(jnp.sum(jnp.where(is_pivot, col, 0.0),
+                                       axis=0, keepdims=True))
+        factor = col / pivot_val                        # (K, 1)
+        pivot_row = jnp.sum(jnp.where(is_pivot, W, 0.0), axis=0,
+                            keepdims=True)              # W[pj, :]  (1, R)
+        # eliminate column j everywhere but the pivot row
+        W = jnp.where(is_pivot, W, W - factor * pivot_row)
+        avail = jnp.where(is_pivot, 0.0, avail)
+        pivots = jnp.where(slots == j, pj, pivots)
+        return W, avail, pivots, logvol + jnp.log(jnp.abs(pivot_val))
+
+    _, _, pivots, logvol = jax.lax.fori_loop(
+        0, rank, body,
+        (V, jnp.ones((K, 1), jnp.float32), jnp.zeros((1, rank), jnp.float32),
+         jnp.zeros((1, 1), jnp.float32)))
+    return pivots, logvol
+
+
+def gather_columns(G: jax.Array, pivots: jax.Array) -> jax.Array:
+    """``G[:, pivots]`` for G (d, K) and f32 pivots (1, rank) — exact."""
+    d, K = G.shape
+    rank = pivots.shape[1]
+    lanes = _iota((1, K), 1).astype(jnp.float32)
+    slots = _iota((1, rank), 1)
+
+    def body(j, G_sel):
+        pj = jnp.sum(jnp.where(slots == j, pivots, 0.0), axis=1,
+                     keepdims=True)                     # (1, 1)
+        col = jnp.sum(jnp.where(lanes == pj, G, 0.0), axis=1,
+                      keepdims=True)                    # G[:, pj]  (d, 1)
+        return jnp.where(slots == j, col, G_sel)
+
+    return jax.lax.fori_loop(0, rank, body,
+                             jnp.zeros((d, rank), jnp.float32))
+
+
+def mgs_errors(G: jax.Array, g_bar: jax.Array) -> jax.Array:
+    """Prefix projection errors ``1 - ||Q_r^T g_hat||^2`` of G (d, R)
+    against g_bar (d, 1), by two-pass modified Gram-Schmidt (the
+    arithmetic of ``core.projection.prefix_projection_errors``, with the
+    products summed on the VPU). Returns (1, R) f32."""
+    d, R = G.shape
+    slots = _iota((1, R), 1)
+    g_hat = g_bar / jnp.sqrt(jnp.sum(g_bar * g_bar, axis=0, keepdims=True)
+                             + _EPS)
+
+    def body(j, carry):
+        Q, captured, errs = carry                       # Q: (d, R), cols < j
+        q = jnp.sum(jnp.where(slots == j, G, 0.0), axis=1,
+                    keepdims=True)                      # G[:, j]   (d, 1)
+        for _ in range(2):                              # q -= Q (Q^T q)
+            coef = jnp.sum(Q * q, axis=0, keepdims=True)
+            q = q - jnp.sum(Q * coef, axis=1, keepdims=True)
+        nrm = jnp.sqrt(jnp.sum(q * q, axis=0, keepdims=True))
+        q = jnp.where(nrm > 1e-8, q / (nrm + _EPS), 0.0)
+        Q = jnp.where(slots == j, q, Q)
+        captured = captured + jnp.sum(q * g_hat, axis=0, keepdims=True) ** 2
+        errs = jnp.where(slots == j, jnp.clip(1.0 - captured, 0.0, 1.0),
+                         errs)
+        return Q, captured, errs
+
+    _, _, errs = jax.lax.fori_loop(
+        0, R, body,
+        (jnp.zeros((d, R), jnp.float32), jnp.zeros((1, 1), jnp.float32),
+         jnp.zeros((1, R), jnp.float32)))
+    return errs
 
 
 def _fused_body(V, G, g_bar, rank: int):
-    """The whole refresh on loaded VMEM values.
-
-    V: (K, R) f32; G: (d, K) f32; g_bar: (d,) f32. Returns
-    (pivots (rank,) i32, errors (rank,) f32, logvol () f32,
-    G_sel (d, rank) f32).
-    """
-    K = V.shape[0]
-
-    # --- stage 1: Fast MaxVol (same arithmetic as _fast_maxvol_kernel) ---
-    def mv_body(j, carry):
-        W, avail, pivots, logvol = carry
-        col = W[:, j]
-        scores = jnp.where(avail > 0, jnp.abs(col), -1.0)
-        pj = jnp.argmax(scores)
-        pivot_val = safe_pivot(W[pj, j])
-        factor = col / pivot_val                        # (K,)
-        pivot_row = W[pj, :]                            # (R,)
-        W_new = W - factor[:, None] * pivot_row[None, :]
-        W_new = jnp.where((jax.lax.iota(jnp.int32, K) == pj)[:, None], W, W_new)
-        avail = jnp.where(jax.lax.iota(jnp.int32, K) == pj, 0.0, avail)
-        pivots = pivots.at[j].set(pj.astype(jnp.int32))
-        return W_new, avail, pivots, logvol + jnp.log(jnp.abs(pivot_val))
-
-    _, _, pivots, logvol = jax.lax.fori_loop(
-        0, rank, mv_body,
-        (V, jnp.ones((K,), jnp.float32),
-         jnp.zeros((rank,), jnp.int32), jnp.float32(0.0)))
-
-    # --- stage 2: gather the pivot columns of G as a one-hot matmul ---
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (K, rank), 0)
-              == pivots[None, :]).astype(jnp.float32)
-    G_sel = G @ onehot                                  # (d, rank), exact
-
-    # --- stage 3: MGS prefix sweep (same arithmetic as _projection_sweep_kernel) ---
-    g_hat = g_bar / jnp.sqrt(jnp.sum(g_bar * g_bar) + _EPS)
-
-    def mgs_body(j, carry):
-        Q, captured, errs = carry                       # Q: (d, rank)
-        q = G_sel[:, j]
-        q = q - Q @ (Q.T @ q)
-        q = q - Q @ (Q.T @ q)
-        nrm = jnp.sqrt(jnp.sum(q * q))
-        q = jnp.where(nrm > 1e-8, q / (nrm + _EPS), jnp.zeros_like(q))
-        Q = jnp.where((jax.lax.iota(jnp.int32, rank) == j)[None, :],
-                      q[:, None], Q)
-        captured = captured + jnp.sum(q * g_hat) ** 2
-        errs = errs.at[j].set(jnp.clip(1.0 - captured, 0.0, 1.0))
-        return Q, captured, errs
-
-    d = G.shape[0]
-    _, _, errors = jax.lax.fori_loop(
-        0, rank, mgs_body,
-        (jnp.zeros((d, rank), jnp.float32), jnp.float32(0.0),
-         jnp.zeros((rank,), jnp.float32)))
-    return pivots, errors, logvol, G_sel
+    """The whole refresh on loaded VMEM values: V (K, R), G (d, K),
+    g_bar (d, 1). Returns (pivots (1, rank) f32, errors (1, rank),
+    logvol (1, 1), G_sel (d, rank))."""
+    pivots, logvol = maxvol_loop(V, rank)
+    G_sel = gather_columns(G, pivots)
+    return pivots, mgs_errors(G_sel, g_bar), logvol, G_sel
 
 
 def _fused_kernel(v_ref, g_ref, gbar_ref,
                   piv_ref, err_ref, logvol_ref, gsel_ref, *, rank: int):
-    pivots, errors, logvol, G_sel = _fused_body(
-        v_ref[...], g_ref[...], gbar_ref[...], rank)
-    piv_ref[...] = pivots
-    err_ref[...] = errors
-    logvol_ref[0] = logvol
-    gsel_ref[...] = G_sel
-
-
-def _fused_kernel_batched(v_ref, g_ref, gbar_ref,
-                          piv_ref, err_ref, logvol_ref, gsel_ref, *,
-                          rank: int):
     # every ref carries a leading block dim of 1 (one grid step = one batch)
     pivots, errors, logvol, G_sel = _fused_body(
         v_ref[0], g_ref[0], gbar_ref[0], rank)
-    piv_ref[0] = pivots
+    piv_ref[0] = pivots.astype(jnp.int32)
     err_ref[0] = errors
-    logvol_ref[0, 0] = logvol
+    logvol_ref[0] = logvol
     gsel_ref[0] = G_sel
 
 
 def _check_budget(K: int, R: int, d: int, rank: int) -> None:
-    # resident f32 blocks: V, G, G_sel, the MGS basis Q, and the one-hot —
+    # resident f32 blocks: V, the residual W, G, G_sel and the MGS basis Q —
     # accounted by the shared estimator (repro.analysis.vmem), so the
     # static checker and this runtime guard can never disagree
     from repro.analysis.vmem import fused_select_vmem
@@ -131,6 +167,34 @@ def _check_budget(K: int, R: int, d: int, rank: int) -> None:
         raise ValueError(
             f"fused selection blocks ({est.total / 2**20:.1f} MB) exceed "
             f"the VMEM budget; shrink K={K}, d={d} or rank={rank}")
+
+
+def _select_call(V, G, g_bar, rank: int, interpret: bool):
+    """The ``grid=(B,)`` launch over V (B, K, R), G (B, d, K), g_bar
+    (B, d). Every block's last two dims are the array's own, which is
+    what the TPU lowering accepts for shapes off the (8, 128) tile."""
+    B, K, R = V.shape
+    d = G.shape[1]
+    _check_budget(K, R, d, rank)
+    pivots, errors, logvol, gsel = pl.pallas_call(
+        functools.partial(_fused_kernel, rank=rank),
+        out_shape=(jax.ShapeDtypeStruct((B, 1, rank), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, rank), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, d, rank), jnp.float32)),
+        in_specs=[pl.BlockSpec((1, K, R), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((1, d, K), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((1, d, 1), lambda b: (b, 0, 0))],
+        out_specs=(pl.BlockSpec((1, 1, rank), lambda b: (b, 0, 0)),
+                   pl.BlockSpec((1, 1, rank), lambda b: (b, 0, 0)),
+                   pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
+                   pl.BlockSpec((1, d, rank), lambda b: (b, 0, 0))),
+        grid=(B,),
+        interpret=interpret,
+        name="graft_select",
+    )(V.astype(jnp.float32), G.astype(jnp.float32),
+      g_bar.astype(jnp.float32)[..., None])
+    return pivots[:, 0], errors[:, 0], logvol[:, 0, 0], gsel
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "interpret"))
@@ -150,25 +214,9 @@ def fused_graft_select_pallas(V: jax.Array, G: jax.Array, g_bar: jax.Array,
         raise ValueError(f"g_bar shape {g_bar.shape} != ({d},)")
     if rank > min(K, R):
         raise ValueError(f"rank {rank} > min{V.shape}")
-    _check_budget(K, R, d, rank)
-    kernel = functools.partial(_fused_kernel, rank=rank)
-    pivots, errors, logvol, gsel = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((rank,), jnp.int32),
-                   jax.ShapeDtypeStruct((rank,), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32),
-                   jax.ShapeDtypeStruct((d, rank), jnp.float32)),
-        in_specs=[pl.BlockSpec((K, R), lambda: (0, 0)),
-                  pl.BlockSpec((d, K), lambda: (0, 0)),
-                  pl.BlockSpec((d,), lambda: (0,))],
-        out_specs=(pl.BlockSpec((rank,), lambda: (0,)),
-                   pl.BlockSpec((rank,), lambda: (0,)),
-                   pl.BlockSpec((1,), lambda: (0,)),
-                   pl.BlockSpec((d, rank), lambda: (0, 0))),
-        grid=(),
-        interpret=interpret,
-    )(V.astype(jnp.float32), G.astype(jnp.float32), g_bar.astype(jnp.float32))
-    return pivots, errors, logvol[0], gsel
+    pivots, errors, logvol, gsel = _select_call(
+        V[None], G[None], g_bar[None], rank, interpret)
+    return pivots[0], errors[0], logvol[0], gsel[0]
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "interpret"))
@@ -188,22 +236,4 @@ def fused_graft_select_batched_pallas(V: jax.Array, G: jax.Array,
                          f"g_bar={g_bar.shape}")
     if rank > min(K, R):
         raise ValueError(f"rank {rank} > min({K}, {R})")
-    _check_budget(K, R, d, rank)
-    kernel = functools.partial(_fused_kernel_batched, rank=rank)
-    pivots, errors, logvol, gsel = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((B, rank), jnp.int32),
-                   jax.ShapeDtypeStruct((B, rank), jnp.float32),
-                   jax.ShapeDtypeStruct((B, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((B, d, rank), jnp.float32)),
-        in_specs=[pl.BlockSpec((1, K, R), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, d, K), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, d), lambda b: (b, 0))],
-        out_specs=(pl.BlockSpec((1, rank), lambda b: (b, 0)),
-                   pl.BlockSpec((1, rank), lambda b: (b, 0)),
-                   pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                   pl.BlockSpec((1, d, rank), lambda b: (b, 0, 0))),
-        grid=(B,),
-        interpret=interpret,
-    )(V.astype(jnp.float32), G.astype(jnp.float32), g_bar.astype(jnp.float32))
-    return pivots, errors, logvol[:, 0], gsel
+    return _select_call(V, G, g_bar, rank, interpret)

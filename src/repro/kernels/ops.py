@@ -7,8 +7,13 @@ against ``ref.py`` validate the TPU target logic.
 """
 from __future__ import annotations
 
+import functools
+import re
+from typing import Set
+
 import jax
 
+from repro.distributed.sharding import shard_kernel
 from repro.kernels import fast_maxvol as _fm
 from repro.kernels import graft_select as _gs
 from repro.kernels import projection_sweep as _ps
@@ -17,6 +22,22 @@ from repro.kernels import rwkv_scan as _rw
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+_KERNEL_OP = re.compile(r'op_name="[^"]*?([^"/]*)/pallas_call"')
+
+
+def compiled_kernel_names(hlo_text: str) -> Set[str]:
+    """Names of the Pallas kernels compiled into a program's HLO text
+    (``compiled.as_text()``): each kernel's ``pallas_call(name=...)``,
+    with transform wrappers such as ``transpose(jvp(...))`` removed.
+    Only a chip compile holds these; interpret mode leaves no kernel."""
+    names = set()
+    for op in _KERNEL_OP.findall(hlo_text):
+        while op.endswith(")") and "(" in op:
+            op = op[op.index("(") + 1:-1]
+        names.add(op)
+    return names
 
 
 def fast_maxvol(V: jax.Array, rank: int) -> jax.Array:
@@ -34,22 +55,29 @@ def projection_sweep(G: jax.Array, g_bar: jax.Array) -> jax.Array:
     return _ps.projection_sweep_pallas(G, g_bar, interpret=not _on_tpu())
 
 
+def _replicated(kernel, rank: int, *args):
+    """The selection matrices are tiny and replicated: under a mesh every
+    device runs the kernel on the whole of them (``shard_kernel``).
+    Returns (pivots, errors, G_sel)."""
+    fn = functools.partial(kernel, rank=rank, interpret=not _on_tpu())
+    pivots, errors, _, gsel = shard_kernel(
+        fn, args, [(None,) * a.ndim for a in args], ())
+    return pivots, errors, gsel
+
+
 def fused_graft_select(V: jax.Array, G: jax.Array, g_bar: jax.Array,
                        rank: int):
     """One GRAFT refresh (MaxVol + gather + MGS sweep) in ONE dispatch.
     Returns (pivots (rank,), errors (rank,), G_sel (d, rank))."""
-    pivots, errors, _, gsel = _gs.fused_graft_select_pallas(
-        V, G, g_bar, rank, interpret=not _on_tpu())
-    return pivots, errors, gsel
+    return _replicated(_gs.fused_graft_select_pallas, rank, V, G, g_bar)
 
 
 def fused_graft_select_batched(V: jax.Array, G: jax.Array, g_bar: jax.Array,
                                rank: int):
     """A microbatch stack of refreshes in ONE grid=(B,) launch. Returns
     (pivots (B, rank), errors (B, rank), G_sel (B, d, rank))."""
-    pivots, errors, _, gsel = _gs.fused_graft_select_batched_pallas(
-        V, G, g_bar, rank, interpret=not _on_tpu())
-    return pivots, errors, gsel
+    return _replicated(_gs.fused_graft_select_batched_pallas, rank,
+                       V, G, g_bar)
 
 
 def rwkv_scan(r, k, v, w, u, chunk: int = 32) -> jax.Array:

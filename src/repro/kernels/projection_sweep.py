@@ -19,31 +19,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_EPS = 1e-12
+from repro.kernels.graft_select import mgs_errors
 
 
 def _projection_sweep_kernel(g_ref, gbar_ref, err_ref):
-    """g_ref: (d, R) f32; gbar_ref: (d,) f32; err_ref: (R,) f32 out."""
-    G = g_ref[...]
-    g = gbar_ref[...]
-    d, R = G.shape
-    g_hat = g / jnp.sqrt(jnp.sum(g * g) + _EPS)
-
-    def body(j, carry):
-        Q, captured = carry                      # Q: (d, R) basis (cols < j valid)
-        q = G[:, j]
-        # two-pass MGS against the filled columns (zeros elsewhere are no-ops)
-        q = q - Q @ (Q.T @ q)
-        q = q - Q @ (Q.T @ q)
-        nrm = jnp.sqrt(jnp.sum(q * q))
-        q = jnp.where(nrm > 1e-8, q / (nrm + _EPS), jnp.zeros_like(q))
-        Q = jnp.where((jax.lax.iota(jnp.int32, R) == j)[None, :], q[:, None], Q)
-        captured = captured + jnp.sum(q * g_hat) ** 2
-        err_ref[j] = jnp.clip(1.0 - captured, 0.0, 1.0)
-        return Q, captured
-
-    Q0 = jnp.zeros((d, R), dtype=jnp.float32)
-    jax.lax.fori_loop(0, R, body, (Q0, jnp.float32(0.0)))
+    """g_ref: (d, R) f32; gbar_ref: (d, 1) f32; err_ref: (1, R) f32 out."""
+    err_ref[...] = mgs_errors(g_ref[...], gbar_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -55,10 +36,11 @@ def projection_sweep_pallas(G: jax.Array, g_bar: jax.Array,
         raise ValueError("G exceeds the single-block VMEM budget; reduce d or R")
     return pl.pallas_call(
         _projection_sweep_kernel,
-        out_shape=jax.ShapeDtypeStruct((R,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, R), jnp.float32),
         in_specs=[pl.BlockSpec((d, R), lambda: (0, 0)),
-                  pl.BlockSpec((d,), lambda: (0,))],
-        out_specs=pl.BlockSpec((R,), lambda: (0,)),
+                  pl.BlockSpec((d, 1), lambda: (0, 0))],
+        out_specs=pl.BlockSpec((1, R), lambda: (0, 0)),
         grid=(),
         interpret=interpret,
-    )(G.astype(jnp.float32), g_bar.astype(jnp.float32))
+        name="projection_sweep",
+    )(G.astype(jnp.float32), g_bar.astype(jnp.float32)[:, None])[0]

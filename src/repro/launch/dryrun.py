@@ -26,7 +26,6 @@ from typing import Any, Dict, Optional
 import jax
 
 from repro import configs as config_lib
-from repro.compat import cost_analysis_dict
 from repro.distributed import sharding as sh
 from repro.launch import specs as specs_lib
 from repro.launch.mesh import make_mesh, make_production_mesh
@@ -127,7 +126,7 @@ def compile_cell(cell: specs_lib.Cell, mesh) -> Dict[str, Any]:
         t1 = time.time()
         compiled = lowered.compile()
         t2 = time.time()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = parse_collective_bytes(hlo)
     result = {

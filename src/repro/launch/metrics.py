@@ -37,7 +37,20 @@ import jax
 
 from repro.analysis.sync_guard import sync_allowed
 
-PEAK_FLOPS_PER_CHIP = 197e12
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s inter-chip interconnect). A kind missing here has
+# no peak: its ``mfu`` is null, never computed against an assumed one.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s": 1600e9 / 8},
+}
+
+
+def peak_flops(device_kind: str) -> Optional[float]:
+    """bf16 peak FLOP/s of one chip of this kind; ``None`` if not tabled."""
+    peaks = DEVICE_PEAKS.get(device_kind)
+    return peaks["bf16_flops"] if peaks else None
 
 
 def _attn_kv_horizon(S: int, window: Optional[int]) -> float:
@@ -325,10 +338,13 @@ class MetricsLogger:
     def __init__(self, path: Optional[str] = None, num_chips: int = 1,
                  flops_per_step: Optional[float] = None,
                  flush_every: int = 20,
-                 device_clock: Optional[DeviceClock] = None):
+                 device_clock: Optional[DeviceClock] = None,
+                 peak_flops_per_chip: Optional[float] = None):
         self.path = path
         self.num_chips = num_chips
         self.flops_per_step = flops_per_step
+        # None (a device kind without a tabled peak) makes ``mfu`` null
+        self.peak_flops_per_chip = peak_flops_per_chip
         self.flush_every = max(1, flush_every)
         self.device_clock = device_clock
         self._f = open(path, "a") if path else None
@@ -363,8 +379,7 @@ class MetricsLogger:
             if tokens:
                 base["tokens_per_s"] = tokens / dt
             if self.flops_per_step:
-                base["mfu"] = (self.flops_per_step /
-                               (dt * self.num_chips * PEAK_FLOPS_PER_CHIP))
+                base["mfu"] = self._mfu(dt)
                 base["mfu_source"] = "dispatch"
             if step_time is not None and gap is not None:
                 base["host_overhead_s"] = max(0.0, gap - step_time)
@@ -376,6 +391,12 @@ class MetricsLogger:
             if len(self._pending) >= self.flush_every:
                 self.flush()
         return base
+
+    def _mfu(self, step_time: float) -> Optional[float]:
+        if self.peak_flops_per_chip is None:
+            return None
+        return self.flops_per_step / (step_time * self.num_chips *
+                                      self.peak_flops_per_chip)
 
     def flush(self):
         """Drain the pending rows: materialize device values (the only
@@ -399,9 +420,7 @@ class MetricsLogger:
                         if tokens:
                             row["tokens_per_s"] = tokens / dev_dt
                         if self.flops_per_step:
-                            row["mfu"] = (self.flops_per_step /
-                                          (dev_dt * self.num_chips *
-                                           PEAK_FLOPS_PER_CHIP))
+                            row["mfu"] = self._mfu(dev_dt)
                             row["mfu_source"] = "device"
                 lines.append(json.dumps(sanitize_row(row), allow_nan=False))
         self._pending.clear()

@@ -12,7 +12,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, shard_kernel
 
 
 # ---------------------------------------------------------------------------
@@ -102,15 +102,12 @@ def _flash_attention(cfg, q: jax.Array, k: jax.Array, v: jax.Array,
     pre-scaled (kernel scale=1); k/v (B,S,Hkv,Dh) — streams fold head-major
     so GQA q stream i reads kv stream i // group without repeating K/V.
     Assumes contiguous from-zero positions (forward_hiddens' layout); the
-    cache/decode path never routes here.
+    cache/decode path never routes here. Under a multi-device mesh each
+    device runs the kernel on its batch shard (``shard_kernel``).
     """
     from repro.kernels import flash_attention as _fa
-    B, S, H, Dh = q.shape
-    Hkv = k.shape[2]
+    S = q.shape[1]
     bq, bk = _flash_blocks(S, S)
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, Dh)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
     if cfg.sliding_window is None:
         window = None
     elif isinstance(is_local, bool):
@@ -119,11 +116,26 @@ def _flash_attention(cfg, q: jax.Array, k: jax.Array, v: jax.Array,
         # traced per-layer local/global pattern (gemma2's scanned
         # alternation): dynamic window operand; w >= S is a no-op mask.
         window = jnp.where(is_local, cfg.sliding_window, S).astype(jnp.int32)
-    out = _fa.flash_attention_pallas(
-        qf, kf, vf, block_q=bq, block_k=bk, causal=True, window=window,
-        softcap=cfg.attn_logit_softcap, group=H // Hkv, scale=1.0,
-        interpret=jax.default_backend() != "tpu")
-    return out.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
+
+    def kernel(q, k, v, *w):
+        B, _, H, Dh = q.shape
+        Hkv = k.shape[2]
+        qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, Dh)
+        kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
+        vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
+        out = _fa.flash_attention_pallas(
+            qf, kf, vf, block_q=bq, block_k=bk, causal=True,
+            window=w[0] if w else None,
+            softcap=cfg.attn_logit_softcap, group=H // Hkv, scale=1.0,
+            interpret=jax.default_backend() != "tpu")
+        return out.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
+
+    rows = ("act_batch", None, None, None)
+    args, logical = (q, k, v), (rows,) * 3
+    if window is not None:
+        args = args + (jnp.asarray(window, jnp.int32),)
+        logical = logical + ((),)
+    return shard_kernel(kernel, args, logical, rows)
 
 
 def attention(cfg, p, x: jax.Array, positions: jax.Array,

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import projection as proj_lib
 from repro.distributed import sharding as sh
 from repro.selection import graft as graft_lib
@@ -271,7 +271,7 @@ def _sharded_selector_cached(cfg: GraftConfig, smp: Sampler, mesh: Mesh,
                 step=jnp.int32(step))
             return state, _sync_carry(carry)
 
-    # check_rep=False: the scan/fori_loop bodies inside MaxVol and the MGS
+    # check_vma=False: the scan/fori_loop bodies inside MaxVol and the MGS
     # sweep defeat shard_map's conservative replication inference even though
     # every P() output is pmean/psum-replicated by construction.
     fn = shard_map(shard_fn, mesh=mesh,
@@ -280,7 +280,7 @@ def _sharded_selector_cached(cfg: GraftConfig, smp: Sampler, mesh: Mesh,
                    out_specs=(SelectionState(P(entry), P(entry), P(),
                                              P(), P(), P()),
                               P()),
-                   check_rep=False)
+                   check_vma=False)
     jitted = jax.jit(fn)
 
     def selector(V, G, step=0, *, scores=None, carry=None):

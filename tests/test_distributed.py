@@ -5,10 +5,11 @@ import numpy as np
 import jax
 import pytest
 
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from conftest import run_forced_devices
 from repro.distributed import sharding as sh
+from repro.launch.mesh import make_mesh
 
 
 def run_py(code: str, devices: int = 8, timeout: int = 480) -> str:
@@ -17,8 +18,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 480) -> str:
 
 class TestShardingRules:
     def _mesh(self):
-        dev = np.array(jax.devices()[:1]).reshape(1, 1)
-        return Mesh(dev, ("data", "model"))
+        return make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
 
     def test_logical_to_spec_filters_missing_axes(self):
         mesh = self._mesh()
@@ -27,8 +27,7 @@ class TestShardingRules:
         assert spec == P(("data",), None, "model")
 
     def test_drop_indivisible(self):
-        dev = np.array(jax.devices()[:1]).reshape(1, 1)
-        mesh = Mesh(dev, ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
         # sizes are 1 so everything divides; use a fake larger mesh via spec
         spec = sh.drop_indivisible(P("data", "model"), (4, 4), mesh)
         assert spec == P("data", "model")
@@ -110,10 +109,11 @@ class TestCompressionMultiDevice:
     def test_ef_psum_across_8_shards(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import Mesh, PartitionSpec as P
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
             from repro.distributed import compression
-            mesh = Mesh(np.array(jax.devices()).reshape(8), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("pod",))
             g = jnp.asarray(np.random.default_rng(0).normal(
                 size=(8, 512)).astype(np.float32))
             e = jnp.zeros((8, 512))
@@ -137,7 +137,7 @@ class TestElasticRestore:
         cm = CheckpointManager(str(tmp_path))
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         cm.save(1, tree)
-        mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("data",))
+        mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
         shard = jax.sharding.NamedSharding(mesh, P("data", None))
         out = cm.restore(1, tree, sharding_tree={"w": shard})
         assert out["w"].sharding.is_equivalent_to(shard, 2)
@@ -150,9 +150,9 @@ class TestPipelineParallel:
     def test_gpipe_matches_sequential(self):
         out = run_py("""
             import numpy as np, jax, jax.numpy as jnp
-            from jax.sharding import Mesh
             from repro.distributed.pipeline import pipeline_forward
-            mesh = Mesh(np.array(jax.devices()[:4]), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("pod",), devices=jax.devices()[:4])
             S, M, mb, D = 4, 3, 2, 8
             rng = np.random.default_rng(0)
             Ws = jnp.asarray(rng.normal(size=(S, D, D)).astype(np.float32) * 0.3)

@@ -7,6 +7,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.extend import core as jex_core
 
 from repro import configs
 from repro.models import decode as Dec
@@ -229,9 +230,9 @@ class TestAttnBackends:
                     for v in eqn.params.values():
                         for sub in (v if isinstance(v, (list, tuple))
                                     else [v]):
-                            if isinstance(sub, jax.core.ClosedJaxpr):
+                            if isinstance(sub, jex_core.ClosedJaxpr):
                                 walk(sub.jaxpr)
-                            elif isinstance(sub, jax.core.Jaxpr):
+                            elif isinstance(sub, jex_core.Jaxpr):
                                 walk(sub)
             walk(jax.make_jaxpr(fn)(*args).jaxpr)
             return n

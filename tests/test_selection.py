@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from conftest import run_forced_devices
+from repro.launch.mesh import make_mesh
 from repro.selection import (GraftConfig, Sampler, SelectionInputs,
                              SelectionState, available, engine, get_sampler,
                              init_state, register)
@@ -219,7 +220,7 @@ class TestVmappedMultiBatch:
 class TestShardedSelection:
     def test_single_device_mesh_matches_reference(self, rng):
         V, G, gb = _inputs(rng)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         sharded, _ = engine.select_sharded(CFG, mesh, V, G)
         single, _ = engine.select_batch(CFG, "graft", V, G, gb)
         np.testing.assert_array_equal(np.asarray(sharded.pivots),
@@ -231,20 +232,20 @@ class TestShardedSelection:
                                    float(single.alignment), atol=1e-5)
 
     def test_selector_is_cached(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         a = engine.make_sharded_selector(CFG, mesh)
         b = engine.make_sharded_selector(CFG, mesh)
         assert a is b, "sharded selector must not re-trace per call"
 
     def test_input_validation(self, rng):
         V, G, _ = _inputs(rng, K=6, r=8)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with pytest.raises(ValueError, match="r_max"):
             engine.select_sharded(CFG, mesh, V, G)
 
     def test_no_data_axis_rejected(self, rng):
         V, G, _ = _inputs(rng)
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         with pytest.raises(ValueError, match="no axis"):
             engine.select_sharded(CFG, mesh, V, G)
 
@@ -255,6 +256,7 @@ class TestShardedSelection:
         the psum'd global rank decision must equal the single-device one."""
         code = """
             import numpy as np, jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.selection import GraftConfig, engine
             assert len(jax.devices()) == 4
             rng = np.random.default_rng(0)
@@ -263,7 +265,7 @@ class TestShardedSelection:
             V1 = jnp.asarray(rng.normal(size=(K, 8)).astype(np.float32))
             G1 = jnp.asarray(rng.normal(size=(d, K)).astype(np.float32))
             single, _ = engine.select_batch(cfg, "graft", V1, G1, jnp.mean(G1, axis=1))
-            mesh = jax.make_mesh((2, 2), ("data", "model"))  # 2-way data sharding
+            mesh = make_mesh((2, 2), ("data", "model"))  # 2-way data sharding
             n_sh = 2
             sharded, _ = engine.select_sharded(cfg, mesh,
                                                jnp.tile(V1, (n_sh, 1)),
@@ -373,12 +375,13 @@ class TestSamplerV2Conformance:
         device count is fixed at backend init)."""
         code = """
             import numpy as np, jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.selection import GraftConfig, available, engine, get_sampler
             assert len(jax.devices()) == 4
             rng = np.random.default_rng(0)
             K, d = 16, 12
             cfg = GraftConfig(rset=(2, 4), eps=0.2)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             n_sh = 2
             V = jnp.asarray(rng.normal(size=(n_sh * K, cfg.r_max)).astype(np.float32))
             G = jnp.asarray(rng.normal(size=(d, n_sh * K)).astype(np.float32))

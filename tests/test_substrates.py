@@ -223,9 +223,10 @@ class TestCompression:
 
     def test_ef_compressed_psum_under_shard_map(self):
         """Single-device shard_map sanity: reduces to identity mean."""
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-        mesh = Mesh(np.array(jax.devices()[:1]), ("pod",))
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("pod",), devices=jax.devices()[:1])
         g = jnp.asarray(np.random.default_rng(0).normal(size=(256,)).astype(np.float32))
         e = jnp.zeros((256,))
 
@@ -340,7 +341,7 @@ class TestMetricsAndEval:
         from repro.launch.metrics import MetricsLogger, read_metrics
         path = str(tmp_path / "t.jsonl")
         lg = MetricsLogger(path, num_chips=1, flops_per_step=1e12,
-                           flush_every=1)
+                           flush_every=1, peak_flops_per_chip=197e12)
         lg.log(0, {"loss": 2.0}, tokens=100, step_time=0.01)
         time_mod.sleep(0.08)                  # simulated eval pause
         lg.log(1, {"loss": 1.9}, tokens=100, step_time=0.01)
@@ -401,7 +402,6 @@ class TestMetricsAndEval:
         O(S²·Dh·H) attention work, so at fixed tokens the param-only
         estimate is flat in S while compiled FLOPs grow. The
         attention-aware estimate must track that growth better."""
-        from repro.compat import cost_analysis_dict
         from repro.launch.metrics import (attention_train_flops,
                                           train_step_flops)
         from repro.models import model as M
@@ -420,7 +420,7 @@ class TestMetricsAndEval:
                 return jax.grad(lambda pp: M.loss_fn(cfg, pp, b)[0])(p)
 
             compiled = jax.jit(step).lower(params, batch).compile()
-            measured = cost_analysis_dict(compiled).get("flops", 0.0)
+            measured = compiled.cost_analysis().get("flops", 0.0)
             n = sum(int(np.prod(l.shape))
                     for l in jax.tree_util.tree_leaves(params))
             return (measured,

@@ -94,10 +94,9 @@ class TestComplexityScaling:
         K = 512
 
         def flops(R):
-            from repro.compat import cost_analysis_dict
             V = jnp.zeros((K, R), jnp.float32)
             c = jax.jit(lambda v: fast_maxvol(v, R)).lower(V).compile()
-            return cost_analysis_dict(c).get("flops", 0.0)
+            return c.cost_analysis().get("flops", 0.0)
 
         f8, f16, f32 = flops(8), flops(16), flops(32)
         # growth ratio between successive doublings should be ≲ 4 (R² term)
