@@ -36,8 +36,8 @@ from repro.checkpoint import CheckpointManager, EmergencySaver
 from repro.distributed.straggler import StragglerMonitor
 from repro.launch.evaluate import make_eval_fn_for
 from repro.launch.metrics import (MetricsLogger, format_step_line,
-                                  materialize_metrics, peak_flops,
-                                  sanitize_row, train_step_flops)
+                                  graft_step_flops, materialize_metrics,
+                                  peak_flops, sanitize_row, train_step_flops)
 from repro.selection.overlap import SideStream
 
 
@@ -147,7 +147,9 @@ class MetricsCallback(Callback):
     only every ``flush_every`` steps and on close, so ``on_step_end`` pays
     neither a device sync nor a write syscall per step. Step timing comes
     from the trainer's dispatch clock, not the gap between log calls —
-    eval/checkpoint pauses land in ``host_overhead_s``, not in ``mfu``."""
+    eval/checkpoint pauses land in ``host_overhead_s``, not in ``mfu``.
+    A GRAFT row's ``mfu`` counts the work its own rank required
+    (``graft_step_flops``), read at flush."""
     priority = 30
 
     def __init__(self, path: Optional[str] = None, flush_every: int = 20):
@@ -158,6 +160,18 @@ class MetricsCallback(Callback):
 
     def on_train_start(self, trainer) -> None:
         tr = trainer.config.train
+        row_flops = None
+        if trainer.tcfg.use_graft:
+            every = trainer.tcfg.graft.refresh_every
+
+            def row_flops(row):
+                # a GRAFT step's work follows the rank it chose
+                if "rank" not in row:
+                    return None
+                return graft_step_flops(
+                    trainer.num_params, trainer.mcfg, tr.batch, tr.seq,
+                    trainer.tcfg.probe_positions, int(row["rank"]),
+                    refresh=row["step"] % every == 0)
         self.logger = MetricsLogger(
             self.path, num_chips=trainer.backend.device_count(),
             flops_per_step=train_step_flops(
@@ -166,7 +180,8 @@ class MetricsCallback(Callback):
                 mcfg=trainer.mcfg, seq=tr.seq),
             flush_every=self.flush_every,
             device_clock=trainer.device_clock,
-            peak_flops_per_chip=peak_flops(jax.devices()[0].device_kind))
+            peak_flops_per_chip=peak_flops(jax.devices()[0].device_kind),
+            row_flops=row_flops)
 
     def on_step_end(self, trainer, step, metrics) -> None:
         tr = trainer.config.train

@@ -42,6 +42,7 @@ from repro.api.config import ExperimentConfig
 from repro.distributed import sharding as sh
 from repro.distributed.pipeline import BatchStager
 from repro.launch import steps as steps_lib
+from repro.launch import tracing
 from repro.launch.metrics import (DeviceClock, MetricsFuture,
                                   materialize_metrics)
 
@@ -240,13 +241,18 @@ class Trainer:
             # module-global so the checkpoint writer (its own thread) sees
             # the crash points too
             chaos_lib.activate(self._chaos)
+        # the span table holds this run's spans and compile events
+        tracing.TABLE.reset()
+        tracing.TABLE.listen()
         # backend first: distributed bring-up must precede ANY device query
         # (mesh construction, data sharding, state init all depend on it)
-        self.backend.setup()
-        self.backend.check_consistent(cfg.config_hash())
-        self.mcfg, self.tcfg, self.data = cfg.build(backend=self.backend)
-        mesh = self.backend.mesh()
-        run_step = steps_lib.make_run_step(self.mcfg, self.tcfg)
+        with tracing.span(tracing.SETUP_BACKEND):
+            self.backend.setup()
+            self.backend.check_consistent(cfg.config_hash())
+        with tracing.span(tracing.SETUP_BUILD):
+            self.mcfg, self.tcfg, self.data = cfg.build(backend=self.backend)
+            mesh = self.backend.mesh()
+            run_step = steps_lib.make_run_step(self.mcfg, self.tcfg)
 
         history = HistoryBuffer(cap=tr.history_cap)
         dispatched_ahead = 0
@@ -267,13 +273,14 @@ class Trainer:
         completed = False
         try:
             with sh.sharding_rules(mesh):
-                self.state = steps_lib.init_train_state(
-                    self.mcfg, self.tcfg, jax.random.PRNGKey(tr.seed),
-                    tr.batch)
-                # every process computes the identical init (same PRNGKey);
-                # replicate makes it the backend's resident form (identity
-                # on local — bit-identical to the pre-backend loop)
-                self.state = self.backend.replicate(self.state)
+                with tracing.span(tracing.SETUP_INIT):
+                    self.state = steps_lib.init_train_state(
+                        self.mcfg, self.tcfg, jax.random.PRNGKey(tr.seed),
+                        tr.batch)
+                    # every process computes the identical init (same
+                    # PRNGKey); replicate makes it the backend's resident
+                    # form, the layout the step returns it in
+                    self.state = self.backend.replicate(self.state)
                 self.num_params = sum(
                     int(np.prod(l.shape)) for l in
                     jax.tree_util.tree_leaves(self.state["params"]))
@@ -300,76 +307,85 @@ class Trainer:
                         audit_scope.enter_context(audit_guard)
                     step = self.start_step
                     while step < tr.steps:
-                        if self._chaos is not None:
-                            self._chaos.fire_signals(step)
-                            batch_np = self._chaos.corrupt_batch(
-                                step, next(it))
-                            batch = self.backend.shard_batch(batch_np)
-                        else:
-                            batch = next(self._stager)
-                        if watcher is not None:
-                            drift = watcher.observe(step=step,
-                                                    state=self.state,
-                                                    batch=batch)
-                            if drift:
-                                raise RuntimeError(
-                                    "[train.audit] " +
-                                    "; ".join(f.message for f in drift))
-                        t0 = time.time()
-                        self.state, dev_metrics = run_step(self.state, batch,
-                                                           step)
-                        self.last_step_time = time.time() - t0
-                        dispatch_s += self.last_step_time
-                        if self.device_clock is not None and dev_metrics:
-                            # metrics are detached (jnp.copy) — safe for
-                            # the clock thread to hold while donated
-                            # buffers are reused
-                            marker = dev_metrics.get(
-                                "loss", next(iter(dev_metrics.values())))
-                            if self._chaos is not None:
-                                marker = self._chaos.wrap_marker(step,
-                                                                 marker)
-                            self.device_clock.observe(step, marker)
-                        # dispatch accounting: run_step returning means
-                        # step N is ISSUED; if step N−1's metrics are
-                        # still device futures at that point, the host ran
-                        # ahead of the device queue
-                        if prev_row is not None and not prev_row.materialized:
-                            dispatched_ahead += 1
-                        metrics = MetricsFuture(dev_metrics)
-                        prev_row = metrics
-                        self._fire("on_step_end", step, metrics)
-                        history.append(metrics)
-                        if self._rollback_reason is not None:
-                            resumed = self._perform_rollback(step)
-                            if resumed is not None:
-                                step = resumed
-                                prev_row = None
-                                continue
-                        if self.should_stop:
-                            break
+                        tracing.TABLE.step = step
+                        with jax.profiler.StepTraceAnnotation(
+                                tracing.STEP, step_num=step):
+                            with tracing.span(tracing.DATA_WAIT):
+                                if self._chaos is not None:
+                                    self._chaos.fire_signals(step)
+                                    batch_np = self._chaos.corrupt_batch(
+                                        step, next(it))
+                                    batch = self.backend.shard_batch(
+                                        batch_np)
+                                else:
+                                    batch = next(self._stager)
+                            if watcher is not None:
+                                drift = watcher.observe(
+                                    step=step, state=self.state, batch=batch)
+                                if drift:
+                                    raise RuntimeError(
+                                        "[train.audit] " +
+                                        "; ".join(f.message for f in drift))
+                            t0 = time.time()
+                            with tracing.span(tracing.DISPATCH):
+                                self.state, dev_metrics = run_step(
+                                    self.state, batch, step)
+                            self.last_step_time = time.time() - t0
+                            dispatch_s += self.last_step_time
+                            if self.device_clock is not None and dev_metrics:
+                                # metrics are detached (jnp.copy) — safe
+                                # for the clock thread to hold while
+                                # donated buffers are reused
+                                marker = dev_metrics.get(
+                                    "loss", next(iter(dev_metrics.values())))
+                                if self._chaos is not None:
+                                    marker = self._chaos.wrap_marker(
+                                        step, marker)
+                                self.device_clock.observe(step, marker)
+                            # dispatch accounting: run_step returning means
+                            # step N is ISSUED; if step N−1's metrics are
+                            # still device futures at that point, the host
+                            # ran ahead of the device queue
+                            if prev_row is not None and \
+                                    not prev_row.materialized:
+                                dispatched_ahead += 1
+                            metrics = MetricsFuture(dev_metrics)
+                            prev_row = metrics
+                            with tracing.span(tracing.CALLBACKS):
+                                self._fire("on_step_end", step, metrics)
+                            history.append(metrics)
+                            if self._rollback_reason is not None:
+                                resumed = self._perform_rollback(step)
+                                if resumed is not None:
+                                    step = resumed
+                                    prev_row = None
+                                    continue
+                            if self.should_stop:
+                                break
                         step += 1
                 wall = time.time() - t_start
-                last = history.last
-                report: Dict[str, Any] = {
-                    "final_loss": last["loss"] if last is not None else None,
-                    "history": history.rows(),
-                    "wall_s": wall,
-                    "config_hash": cfg.config_hash(),
-                    "host_loop": {
-                        "steps": history.total,
-                        "dispatched_ahead": dispatched_ahead,
-                        "dispatch_s": dispatch_s,
-                    },
-                }
-                if self.device_clock is not None:
-                    self.device_clock.drain()
-                    report["host_loop"]["device_timed_steps"] = \
-                        self.device_clock.timed_steps
-                    report["host_loop"]["device_time_s"] = \
-                        self.device_clock.total_device_s
-                    if self.device_clock.stalled:
-                        report["host_loop"]["device_stalled"] = True
+                with tracing.span(tracing.DRAIN):
+                    last = history.last
+                    report: Dict[str, Any] = {
+                        "final_loss": (last["loss"] if last is not None
+                                       else None),
+                        "history": history.rows(),
+                        "wall_s": wall,
+                        "config_hash": cfg.config_hash(),
+                        "host_loop": {
+                            "steps": history.total,
+                            "dispatched_ahead": dispatched_ahead,
+                            "dispatch_s": dispatch_s,
+                        },
+                    }
+                    if self.device_clock is not None:
+                        self.device_clock.drain()
+                        report["host_loop"]["device_timed_steps"] = \
+                            self.device_clock.timed_steps
+                        report["host_loop"]["device_time_s"] = \
+                            self.device_clock.total_device_s
+                        if self.device_clock.stalled:
+                            report["host_loop"]["device_stalled"] = True
                 if audit_guard is not None:
                     report["audit"] = {
                         "sync_events": len(audit_guard.events),
@@ -386,10 +402,12 @@ class Trainer:
                     report["stopped"] = self.stop_reason
                 if self.rollbacks:
                     report["resilience"] = {"rollbacks": self.rollbacks}
+                report["spans"] = tracing.TABLE.summary()
                 self._fire("on_train_end", report)
             completed = True
             return report
         finally:
+            tracing.TABLE.step = None
             if not completed:
                 # exiting on an exception: on_train_end never fires, but
                 # signal handlers / open files / writer threads must still

@@ -142,8 +142,8 @@ class Backend:
         return jax.device_put(arr)
 
     def replicate(self, tree):
-        """Train-state tree → this backend's resident form. The local
-        backend is the identity (bit-identical to the pre-backend loop)."""
+        """Train-state tree → this backend's resident form (identity
+        unless a backend lays it out on its mesh)."""
         return tree
 
     def to_host(self, tree):

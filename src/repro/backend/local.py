@@ -3,8 +3,8 @@
 The mesh is ``launch.mesh.make_host_mesh()`` (all local devices, 1-D
 ``data`` axis); ``shard_batch`` lays each batch's leading dim out along
 that axis, so on N devices each holds B/N rows (on one device it is a plain
-transfer); ``replicate`` is the identity, and there is no distributed
-runtime to bring up. The bit-identity regression test
+transfer); ``replicate`` lays the state out replicated over the mesh, and
+there is no distributed runtime to bring up. The bit-identity regression test
 (``tests/test_backend.py``) pins the one-device loop against a hand-rolled
 pre-backend loop — trajectory and graft pivots both.
 """
@@ -25,6 +25,15 @@ class LocalBackend(base.Backend):
 
     def shard_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         return assemble_global_batch(self.mesh(), batch)
+
+    def replicate(self, tree):
+        """The state replicated over the mesh, the layout the train step
+        returns it in: the first step then compiles the program every
+        later step runs, where a state left on one device would make the
+        second step compile again. On one device no copy is made."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(tree, NamedSharding(self.mesh(), P()))
 
 
 def _build(cfg: base.LocalBackendConfig) -> LocalBackend:

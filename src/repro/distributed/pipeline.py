@@ -29,6 +29,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
+from repro.launch import tracing
+
 
 # ---------------------------------------------------------------------------
 # cross-process batch staging (backend collaborator)
@@ -91,7 +93,10 @@ class BatchStager:
         if self.depth == 0:
             host = next(self._it)
             self._consumed = self.source.state_dict()
-            return self.stage(host)
+            # a host span on the caller's thread only: with lookahead the
+            # worker stages, and the caller's wait is its own span
+            with tracing.span(tracing.STAGE):
+                return self.stage(host)
         while len(self._queue) < self.depth + 1:
             self._submit()
         fut, state_after = self._queue.popleft()

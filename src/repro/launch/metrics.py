@@ -30,8 +30,8 @@ import os
 import queue
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Mapping, MutableMapping, \
-    Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Mapping, \
+    MutableMapping, Optional, Tuple
 
 import jax
 
@@ -93,6 +93,31 @@ def train_step_flops(num_params: int, tokens_per_step: int,
     total = base * (8.0 / 6.0) if remat else base
     if mcfg is not None and seq:
         total += attention_train_flops(mcfg, seq, tokens_per_step, remat=remat)
+    return total
+
+
+def graft_step_flops(num_params: int, mcfg, batch: int, seq: int,
+                     probe_positions: int, rank: int,
+                     refresh: bool = True) -> float:
+    """Required FLOPs of one GRAFT step: on a refresh step the selection
+    forward over all ``batch`` rows (every layer, the head at the probe
+    positions only), then forward and backward over the ``rank`` rows the
+    selection weighted (the head at every position). Remat's recompute and
+    the zero-weight rows the step also runs are not required work and are
+    not counted. Matmuls at 2·N per token; the attention term as in
+    :func:`attention_train_flops`."""
+    head = mcfg.vocab_size * mcfg.d_model
+    body = num_params - head * (1 if mcfg.tie_embeddings else 2)
+
+    def forward(rows: int, head_positions: int) -> float:
+        tokens = rows * seq
+        return (2.0 * body * tokens + 2.0 * head * head_positions
+                + attention_train_flops(mcfg, seq, tokens, remat=False) / 3.0)
+
+    total = 3.0 * forward(rank, rank * seq)
+    if refresh:
+        stride = max(1, seq // probe_positions) if probe_positions else 1
+        total += forward(batch, batch * len(range(0, seq, stride)))
     return total
 
 
@@ -339,10 +364,15 @@ class MetricsLogger:
                  flops_per_step: Optional[float] = None,
                  flush_every: int = 20,
                  device_clock: Optional[DeviceClock] = None,
-                 peak_flops_per_chip: Optional[float] = None):
+                 peak_flops_per_chip: Optional[float] = None,
+                 row_flops: Optional[Callable[[Dict[str, Any]],
+                                              Optional[float]]] = None):
         self.path = path
         self.num_chips = num_chips
         self.flops_per_step = flops_per_step
+        # a row's FLOPs from its materialized values (a GRAFT row's rank),
+        # counted at flush; replaces ``flops_per_step`` when given
+        self.row_flops = row_flops
         # None (a device kind without a tabled peak) makes ``mfu`` null
         self.peak_flops_per_chip = peak_flops_per_chip
         self.flush_every = max(1, flush_every)
@@ -378,8 +408,8 @@ class MetricsLogger:
             base["step_time_s"] = dt
             if tokens:
                 base["tokens_per_s"] = tokens / dt
-            if self.flops_per_step:
-                base["mfu"] = self._mfu(dt)
+            if self.flops_per_step and self.row_flops is None:
+                base["mfu"] = self._mfu(self.flops_per_step, dt)
                 base["mfu_source"] = "dispatch"
             if step_time is not None and gap is not None:
                 base["host_overhead_s"] = max(0.0, gap - step_time)
@@ -392,11 +422,11 @@ class MetricsLogger:
                 self.flush()
         return base
 
-    def _mfu(self, step_time: float) -> Optional[float]:
+    def _mfu(self, flops: float, step_time: float) -> Optional[float]:
         if self.peak_flops_per_chip is None:
             return None
-        return self.flops_per_step / (step_time * self.num_chips *
-                                      self.peak_flops_per_chip)
+        return flops / (step_time * self.num_chips *
+                        self.peak_flops_per_chip)
 
     def flush(self):
         """Drain the pending rows: materialize device values (the only
@@ -412,6 +442,12 @@ class MetricsLogger:
             for base, metrics, tokens in self._pending:
                 row = dict(base)
                 row.update(materialize_metrics(metrics))
+                flops = self.flops_per_step
+                if self.row_flops is not None:
+                    flops = self.row_flops(row)
+                    if flops and "step_time_s" in row:
+                        row["mfu"] = self._mfu(flops, row["step_time_s"])
+                        row["mfu_source"] = "dispatch"
                 if self.device_clock is not None:
                     dev_dt = self.device_clock.device_time(row["step"],
                                                            timeout=1.0)
@@ -419,8 +455,8 @@ class MetricsLogger:
                         row["device_step_time_s"] = dev_dt
                         if tokens:
                             row["tokens_per_s"] = tokens / dev_dt
-                        if self.flops_per_step:
-                            row["mfu"] = self._mfu(dev_dt)
+                        if flops:
+                            row["mfu"] = self._mfu(flops, dev_dt)
                             row["mfu_source"] = "device"
                 lines.append(json.dumps(sanitize_row(row), allow_nan=False))
         self._pending.clear()

@@ -8,6 +8,11 @@ Three step families:
     ``refresh_every == 1`` the selection is unconditional (dry-run worst
     case); otherwise a ``lax.cond`` reuses the previous subset (paper Alg. 1).
   * ``prefill_step`` / ``decode_step`` — serving paths.
+
+Each train step names its phases with ``jax.named_scope``
+(``repro.launch.tracing``: ``select_forward``, ``select_decide``,
+``train_grad``, ``optimizer``, ``sentinel``), so a device trace splits the
+step by phase. The scopes are metadata only.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.launch import tracing
 from repro.models import decode as decode_lib
 from repro.models import model as model_lib
 from repro.optim import OptimizerConfig, make_optimizer
@@ -210,10 +216,12 @@ def make_selection_refresh(mcfg, tcfg: TrainConfig):
     gcfg = tcfg.graft
 
     def refresh(params, batch, carry, step):
-        V, G, g_bar, scores = selection_inputs(mcfg, tcfg, params, batch)
-        key = selection_base.default_select_key(step)
-        return smp.select(gcfg, selection_base.SelectionInputs(
-            V, G, g_bar, scores, key), carry, step)
+        with jax.named_scope(tracing.SELECT_FORWARD):
+            V, G, g_bar, scores = selection_inputs(mcfg, tcfg, params, batch)
+        with jax.named_scope(tracing.SELECT_DECIDE):
+            key = selection_base.default_select_key(step)
+            return smp.select(gcfg, selection_base.SelectionInputs(
+                V, G, g_bar, scores, key), carry, step)
 
     return refresh
 
@@ -250,11 +258,13 @@ def baseline_train_step(mcfg, tcfg: TrainConfig, state, batch):
 
     if tcfg.microbatches > 1:
         from repro.distributed.accumulate import accumulated_grads
-        loss_val, grads = accumulated_grads(
-            lambda p, mb: model_lib.loss_fn(mcfg, p, mb)[0],
-            state["params"], batch, tcfg.microbatches)
-        params, opt_state, metrics = opt.apply(
-            state["params"], grads, state["opt"], state["step"])
+        with jax.named_scope(tracing.TRAIN_GRAD):
+            loss_val, grads = accumulated_grads(
+                lambda p, mb: model_lib.loss_fn(mcfg, p, mb)[0],
+                state["params"], batch, tcfg.microbatches)
+        with jax.named_scope(tracing.OPTIMIZER):
+            params, opt_state, metrics = opt.apply(
+                state["params"], grads, state["opt"], state["step"])
         new_state = dict(state, params=params, opt=opt_state,
                          step=state["step"] + 1)
         return new_state, dict(metrics, loss=loss_val)
@@ -262,12 +272,33 @@ def baseline_train_step(mcfg, tcfg: TrainConfig, state, batch):
     def loss(params):
         return model_lib.loss_fn(mcfg, params, batch)
 
-    (loss_val, aux), grads = jax.value_and_grad(loss, has_aux=True)(state["params"])
-    params, opt_state, metrics = opt.apply(
-        state["params"], grads, state["opt"], state["step"])
+    with jax.named_scope(tracing.TRAIN_GRAD):
+        (loss_val, aux), grads = jax.value_and_grad(loss, has_aux=True)(
+            state["params"])
+    with jax.named_scope(tracing.OPTIMIZER):
+        params, opt_state, metrics = opt.apply(
+            state["params"], grads, state["opt"], state["step"])
     new_state = dict(state, params=params, opt=opt_state, step=state["step"] + 1)
     metrics = dict(metrics, loss=loss_val)
     return new_state, metrics
+
+
+def _subset_update(mcfg, opt, state, batch, graft_state, k_global: int):
+    """Forward, backward and update over the selected rows, weighted by the
+    selection's weights (zero on inactive rows)."""
+    with jax.named_scope(tracing.TRAIN_GRAD):
+        sub_batch = _take_batch(batch, graft_state.pivots, k_global)
+        weights = graft_state.weights           # (R_max,) sum=1, 0 inactive
+
+        def loss(params):
+            pel = model_lib.per_example_loss(mcfg, params, sub_batch)
+            return jnp.sum(pel * weights)
+
+        loss_val, grads = jax.value_and_grad(loss)(state["params"])
+    with jax.named_scope(tracing.OPTIMIZER):
+        params, opt_state, metrics = opt.apply(
+            state["params"], grads, state["opt"], state["step"])
+    return params, opt_state, metrics, loss_val
 
 
 def graft_train_step(mcfg, tcfg: TrainConfig, state, batch):
@@ -294,16 +325,8 @@ def graft_train_step(mcfg, tcfg: TrainConfig, state, batch):
             lambda _: (state["graft"]._replace(step=state["step"]), carry0),
             None)
 
-    sub_batch = _take_batch(batch, graft_state.pivots, k_global)
-    weights = graft_state.weights                   # (R_max,) sum=1, 0 inactive
-
-    def loss(params):
-        pel = model_lib.per_example_loss(mcfg, params, sub_batch)
-        return jnp.sum(pel * weights)
-
-    loss_val, grads = jax.value_and_grad(loss)(state["params"])
-    params, opt_state, metrics = opt.apply(
-        state["params"], grads, state["opt"], state["step"])
+    params, opt_state, metrics, loss_val = _subset_update(
+        mcfg, opt, state, batch, graft_state, k_global)
     new_state = dict(state, params=params, opt=opt_state,
                      step=state["step"] + 1, graft=graft_state)
     if "sampler_carry" in state:
@@ -321,16 +344,8 @@ def subset_train_step(mcfg, tcfg: TrainConfig, state, batch):
     opt = make_optimizer(tcfg.optimizer)
     k_global = jax.tree_util.tree_leaves(batch)[0].shape[0]
     graft_state = state["graft"]
-    sub_batch = _take_batch(batch, graft_state.pivots, k_global)
-    weights = graft_state.weights
-
-    def loss(params):
-        pel = model_lib.per_example_loss(mcfg, params, sub_batch)
-        return jnp.sum(pel * weights)
-
-    loss_val, grads = jax.value_and_grad(loss)(state["params"])
-    params, opt_state, metrics = opt.apply(
-        state["params"], grads, state["opt"], state["step"])
+    params, opt_state, metrics, loss_val = _subset_update(
+        mcfg, opt, state, batch, graft_state, k_global)
     new_state = dict(state, params=params, opt=opt_state,
                      step=state["step"] + 1,
                      graft=graft_state._replace(step=state["step"] + 1))
@@ -422,8 +437,9 @@ def make_train_step(mcfg, tcfg: TrainConfig, kind: Optional[str] = None):
     def fn(state, batch):
         new_state, metrics = step(mcfg, tcfg, state, batch)
         if use_sentinel and "health" in state:
-            new_state, metrics = apply_sentinel(tcfg, state, new_state,
-                                                metrics)
+            with jax.named_scope(tracing.SENTINEL):
+                new_state, metrics = apply_sentinel(tcfg, state, new_state,
+                                                    metrics)
         return new_state, metrics
     return fn
 
