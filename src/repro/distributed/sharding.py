@@ -114,6 +114,15 @@ def _axis_size(mesh: Mesh, target: MeshAxes) -> int:
     return size
 
 
+def logical_axis_size(name: str) -> int:
+    """Devices the active mesh lays logical axis ``name`` over; 1 outside
+    a mesh."""
+    if not _STATE.enabled or _STATE.mesh is None:
+        return 1
+    entry = logical_to_spec((name,))[0]
+    return 1 if entry is None else _axis_size(_STATE.mesh, entry)
+
+
 def drop_indivisible(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
     """Sanitize a spec against concrete dims: drop entries that don't divide
     the dim size (e.g. kv_heads=4 over a 16-way axis stays replicated) and

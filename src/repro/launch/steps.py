@@ -7,7 +7,15 @@ Three step families:
     + Fast MaxVol + rank choice) followed by subset fwd+bwd+update. With
     ``refresh_every == 1`` the selection is unconditional (dry-run worst
     case); otherwise a ``lax.cond`` reuses the previous subset (paper Alg. 1).
+    ``subset_train_step`` is the update alone, on the stored subset.
   * ``prefill_step`` / ``decode_step`` — serving paths.
+
+The subset's forward and backward run over the selected rows of nonzero
+weight only: ``subset_grads`` picks, on the device, the smallest row count
+of ``rank_ladder`` (the candidate ranks and R_max) past which every weight
+is 0, and runs that count's static-shape branch of one ``lax.switch``. A
+rank-R* selection thus costs R* rows, not R_max; the step reports the count
+as ``subset_rows``.
 
 Each train step names its phases with ``jax.named_scope``
 (``repro.launch.tracing``: ``select_forward``, ``select_decide``,
@@ -22,7 +30,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, logical_axis_size
 from repro.launch import tracing
 from repro.models import decode as decode_lib
 from repro.models import model as model_lib
@@ -283,22 +291,67 @@ def baseline_train_step(mcfg, tcfg: TrainConfig, state, batch):
     return new_state, metrics
 
 
-def _subset_update(mcfg, opt, state, batch, graft_state, k_global: int):
-    """Forward, backward and update over the selected rows, weighted by the
-    selection's weights (zero on inactive rows)."""
+def rank_ladder(rset: Tuple[int, ...], n_rows: int) -> Tuple[int, ...]:
+    """The row counts a subset update can run: the candidate ranks below
+    ``n_rows`` that the active mesh's ``act_batch`` devices divide, and
+    ``n_rows`` itself. Ascending; one entry means no choice."""
+    shards = logical_axis_size("act_batch")
+    return tuple(sorted({r for r in rset if r < n_rows and r % shards == 0}
+                        | {n_rows}))
+
+
+def bucket_index(weights: jax.Array, ladder: Tuple[int, ...]) -> jax.Array:
+    """Index into ``ladder`` of the smallest row count ``b`` for which
+    ``weights[b:]`` are all exactly 0 (the last entry, all rows, when no
+    smaller one is)."""
+    n = weights.shape[0]
+    used = jnp.max(jnp.where(weights != 0, jnp.arange(1, n + 1), 0))
+    return jnp.sum(jnp.asarray(ladder[:-1]) < used).astype(jnp.int32)
+
+
+def subset_grads(mcfg, gcfg, params, batch, graft_state, k_global: int):
+    """``(loss, grads, rows)``: value and gradient of ``Σ pel·w`` over the
+    first ``rows`` selected rows, ``rows`` the smallest entry of
+    ``rank_ladder`` past which every weight is exactly 0.
+
+    A row of weight 0 adds exactly 0 to the loss and to every gradient, so
+    each row count computes the all-rows value up to the order of its f32
+    sums; the all-rows branch is the all-rows computation. One
+    ``lax.switch`` branch a row count, each a static shape."""
+    pivots, weights = graft_state.pivots, graft_state.weights
+    ladder = rank_ladder(gcfg.rset, weights.shape[0])
+
+    def over(rows):
+        def branch(p):
+            sub_batch = _take_batch(batch, pivots[:rows], k_global)
+
+            def loss(q):
+                pel = model_lib.per_example_loss(mcfg, q, sub_batch)
+                return jnp.sum(pel * weights[:rows])
+
+            return jax.value_and_grad(loss)(p)
+        return branch
+
+    # lax.switch calls the only branch of a one-entry ladder directly
+    index = bucket_index(weights, ladder)
+    loss_val, grads = jax.lax.switch(index, [over(r) for r in ladder],
+                                     params)
+    return loss_val, grads, jnp.asarray(ladder, jnp.int32)[index]
+
+
+def _subset_update(mcfg, gcfg, opt, state, batch, graft_state,
+                   k_global: int):
+    """Forward and backward over the selected rows of nonzero weight
+    (``subset_grads``), then the update. Returns ``(params, opt_state,
+    metrics, loss)``; the metrics carry ``subset_rows``, the row count the
+    gradient ran over."""
     with jax.named_scope(tracing.TRAIN_GRAD):
-        sub_batch = _take_batch(batch, graft_state.pivots, k_global)
-        weights = graft_state.weights           # (R_max,) sum=1, 0 inactive
-
-        def loss(params):
-            pel = model_lib.per_example_loss(mcfg, params, sub_batch)
-            return jnp.sum(pel * weights)
-
-        loss_val, grads = jax.value_and_grad(loss)(state["params"])
+        loss_val, grads, rows = subset_grads(
+            mcfg, gcfg, state["params"], batch, graft_state, k_global)
     with jax.named_scope(tracing.OPTIMIZER):
         params, opt_state, metrics = opt.apply(
             state["params"], grads, state["opt"], state["step"])
-    return params, opt_state, metrics, loss_val
+    return params, opt_state, dict(metrics, subset_rows=rows), loss_val
 
 
 def graft_train_step(mcfg, tcfg: TrainConfig, state, batch):
@@ -326,7 +379,7 @@ def graft_train_step(mcfg, tcfg: TrainConfig, state, batch):
             None)
 
     params, opt_state, metrics, loss_val = _subset_update(
-        mcfg, opt, state, batch, graft_state, k_global)
+        mcfg, gcfg, opt, state, batch, graft_state, k_global)
     new_state = dict(state, params=params, opt=opt_state,
                      step=state["step"] + 1, graft=graft_state)
     if "sampler_carry" in state:
@@ -345,7 +398,7 @@ def subset_train_step(mcfg, tcfg: TrainConfig, state, batch):
     k_global = jax.tree_util.tree_leaves(batch)[0].shape[0]
     graft_state = state["graft"]
     params, opt_state, metrics, loss_val = _subset_update(
-        mcfg, opt, state, batch, graft_state, k_global)
+        mcfg, tcfg.graft, opt, state, batch, graft_state, k_global)
     new_state = dict(state, params=params, opt=opt_state,
                      step=state["step"] + 1,
                      graft=graft_state._replace(step=state["step"] + 1))

@@ -5,8 +5,10 @@ Pipeline per refresh step (every ``S`` iterations):
   2. Fast MaxVol: pivot order p (prefixes = candidate subsets for every rank)
   3. gradient matrix G[:, j] = grad-embedding of sample p_j; ḡ = batch mean
   4. prefix projection errors d_r; R* = smallest candidate rank with d ≤ ε
-  5. emit (pivots, R*, weights) — weights mask pivots beyond R* so downstream
-     train steps keep a static shape (R_max) while training on R* samples.
+  5. emit (pivots, R*, weights) — the R* active pivots first, weight 0 on
+     the pivots beyond R*. The state keeps a static shape (R_max); the train
+     step runs its forward and backward over the first R* rows only
+     (``launch/steps.py:subset_grads`` reads the zero tail of the weights).
 
 Between refreshes the previous selection is reused (Alg. 1 'else' branch).
 
