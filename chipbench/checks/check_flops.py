@@ -34,8 +34,11 @@ def main() -> int:
         num_heads=8, num_kv_heads=2, head_dim=64, d_ff=1408, vocab_size=2048,
         remat="none", attn_backend="dense", param_dtype="float32",
         scan_layers=False)
-    dims = flops.Dims(cfg.d_model, cfg.d_ff, cfg.num_heads, cfg.num_kv_heads,
-                      cfg.head_dim, cfg.num_layers, cfg.vocab_size)
+    dims = flops.Dims.from_config({
+        "hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+        "num_hidden_layers": cfg.num_layers, "vocab_size": cfg.vocab_size})
     K, R, S, P = 4, 2, 128, 32
     params = jax.eval_shape(lambda: model_lib.init_params(
         cfg, jax.random.PRNGKey(0)))

@@ -3,6 +3,8 @@ counted from shapes. This is the benchmark's own count: matrix
 multiplications at two operations a multiply-add, remat's recompute not
 counted, elementwise work left out.
 
+Each layer's forward is counted by the configuration's block file
+(``reference/blocks/<block>.py:layer_forward_flops``); the head here.
 A GRAFT step does
 
 * on a refresh step (every ``refresh_every`` steps, from step 0) the
@@ -19,37 +21,31 @@ XLA's own ``cost_analysis()`` on a small configuration on the CPU.
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple
+from types import ModuleType
+from typing import Any, Dict, NamedTuple
+
+from chipbench.reference import blocks
 
 
 class Dims(NamedTuple):
     d: int          # hidden
-    f: int          # MLP width
-    heads: int
-    kv_heads: int
-    head_dim: int
     layers: int
     vocab: int
+    block: ModuleType       # the configuration's block file
+    bd: Any                 # its dimensions (``block.Dims``)
 
     @classmethod
     def from_config(cls, c: Dict) -> "Dims":
-        return cls(c["hidden_size"], c["intermediate_size"],
-                   c["num_attention_heads"], c["num_key_value_heads"],
-                   c["head_dim"], c["num_hidden_layers"], c["vocab_size"])
+        block = blocks.of(c)
+        return cls(c["hidden_size"], c["num_hidden_layers"], c["vocab_size"],
+                   block, block.Dims.from_config(c))
 
 
 def layer_forward_flops(m: Dims, rows: int, seq: int,
                         causal: bool = True) -> float:
-    """One layer's forward over ``rows`` sequences of ``seq`` tokens:
-    q/k/v/o projections, the SwiGLU MLP, and attention (with ``causal``,
-    query i attends to i + 1 keys; else to all ``seq``)."""
-    tokens = rows * seq
-    proj = 2 * m.d * (m.heads + 2 * m.kv_heads) * m.head_dim \
-        + 2 * m.heads * m.head_dim * m.d
-    mlp = 3 * 2 * m.d * m.f
-    keys = (seq + 1) / 2 if causal else seq
-    attn = rows * 2 * 2 * m.heads * m.head_dim * seq * keys
-    return tokens * (proj + mlp) + attn
+    """One layer's forward over ``rows`` sequences of ``seq`` tokens, as
+    the configuration's block file counts it (``layer_forward_flops``)."""
+    return m.block.layer_forward_flops(m.bd, rows, seq, causal)
 
 
 def head_flops(m: Dims, positions: int) -> float:

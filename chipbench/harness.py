@@ -38,18 +38,18 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT = os.path.join(HERE, "out")
 
-# config-file keys (Hugging Face names) → the repo's ModelConfig fields
+# config-file keys (Hugging Face names) → the repo's ModelConfig fields:
+# those every block shares (the reference's ``model.py`` reads them); a
+# block file's own ``KEYS`` add the rest
 MODEL_KEYS = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "num_hidden_layers": "num_layers",
-    "vocab_size": "vocab_size", "rms_norm_eps": "rms_eps",
-    "rope_theta": "rope_theta", "hidden_act": "mlp_activation",
-    "tie_word_embeddings": "tie_embeddings", "torch_dtype": "param_dtype",
+    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
+    "vocab_size": "vocab_size", "tie_word_embeddings": "tie_embeddings",
+    "torch_dtype": "param_dtype",
 }
 # configuration-file keys that describe the file rather than set the model
+# (``block`` names the reference's block file, ``reference/blocks/``)
 CONFIG_NOTES = {"name", "source", "paper", "arch", "reduced", "assumed",
-                "departures", "deployment"}
+                "departures", "deployment", "block"}
 TRAFFIC_KEYS = {"name", "kind", "why", "seq_len", "batch_per_chip",
                 "pool_steps", "num_clusters", "cluster_stickiness", "sampler",
                 "graft", "probe_positions", "loss_chunk", "train_steps",
@@ -113,18 +113,20 @@ def experiment(cell: Cell, seed: int):
     """The cell's ``ExperimentConfig``: library defaults, with the model,
     loop, selection and optimizer fields the cell's files give. Refuses a
     file with a key that neither the program's run nor the reference
-    would follow."""
+    would follow: the common ``MODEL_KEYS``, the ``KEYS`` of the block
+    file it names, and ``CONFIG_NOTES``."""
     from repro.api import ExperimentConfig
 
     from chipbench import traffic as traffic_lib
+    from chipbench.reference import blocks
     from chipbench.reference.train import check_traffic
     tr = cell.traffic
+    keys = {**MODEL_KEYS, **blocks.of(cell.config).KEYS}
     refuse_unknown(f"configuration of {cell.name}", cell.config,
-                   set(MODEL_KEYS) | CONFIG_NOTES)
+                   set(keys) | CONFIG_NOTES)
     refuse_unknown(f"traffic of {cell.name}", tr, TRAFFIC_KEYS)
     check_traffic(tr)
-    model = {MODEL_KEYS[k]: v for k, v in cell.config.items()
-             if k in MODEL_KEYS}
+    model = {keys[k]: v for k, v in cell.config.items() if k in keys}
     model["loss_chunk"] = tr["loss_chunk"]
     overrides = [
         f"model.arch={cell.config['arch']}", "model.smoke=false",

@@ -1,3 +1,3 @@
 """Plain float32 reference of the cells' training steps (see
-``model.py``, ``select.py`` and ``train.py``). It imports nothing of the
-program."""
+``model.py``, the block files in ``blocks/``, ``select.py`` and
+``train.py``). It imports nothing of the program."""

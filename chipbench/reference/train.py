@@ -14,6 +14,15 @@ stored type (bfloat16 matrices, float32 norm scales), taken to float32 for
 every use and rounded back after each update; the gradient and AdamW's
 moments are float32.
 
+The chip holds the weights, the chosen rows' activations and one part of
+the float32 state at a time; the gradient and AdamW's moments wait on the
+host between uses. Every float32 operation on a leaf is the one a
+whole-model step would make, in the same order (``_gradient``,
+``_update``): on the CPU the readings are those of a whole-model step bit
+for bit (``tests/test_blocks.py``). On a TPU, XLA compiles a layer's
+backward into other code when it sums into one layer's gradient instead of
+the stacked whole, and the gradient can differ in its last bits.
+
 What it returns is what the program's run is compared with: each step's
 loss and the rows it trained on (``select``; its own decisions on the
 refresh steps are ``decided``), each leaf's norm of the first (clipped)
@@ -94,48 +103,140 @@ def learning_rate(opt: Dict[str, Any], total_steps: int, step: int) -> float:
 
 def _adamw(opt: Dict[str, Any]):
     """AdamW on the stored weights: each leaf taken to float32, updated, and
-    rounded back to its stored type; the moments are float32."""
+    rounded back to its stored type; the moments are float32. Two programs:
+    for a whole leaf, and for layer ``i`` of a stacked one."""
     b1, b2, eps, wd = (opt["beta1"], opt["beta2"], opt["eps"],
                        opt["weight_decay"])
 
-    def apply(p, g, mo, ve, lr, t, clip_scale):
-        def leaf(p, g, mo, ve):
-            p32, g = p.astype(jnp.float32), g * clip_scale
-            mo = b1 * mo + (1 - b1) * g
-            ve = b2 * ve + (1 - b2) * g * g
-            upd = (mo / (1 - b1 ** t)) / (jnp.sqrt(ve / (1 - b2 ** t)) + eps) \
-                + wd * p32
-            return (p32 - lr * upd).astype(p.dtype), mo, ve
-        out = jax.tree_util.tree_map(leaf, p, g, mo, ve)
-        pick = lambda i: jax.tree_util.tree_map(    # noqa: E731
-            lambda x: x[i], out, is_leaf=lambda x: isinstance(x, tuple))
-        return pick(0), pick(1), pick(2)
+    def leaf(p, g, mo, ve, lr, t, clip_scale):
+        p32, g = p.astype(jnp.float32), g * clip_scale
+        mo = b1 * mo + (1 - b1) * g
+        ve = b2 * ve + (1 - b2) * g * g
+        upd = (mo / (1 - b1 ** t)) / (jnp.sqrt(ve / (1 - b2 ** t)) + eps) \
+            + wd * p32
+        return (p32 - lr * upd).astype(p.dtype), mo, ve
 
-    return jax.jit(apply, donate_argnums=(0, 2, 3))
+    def one_layer(stack, i, g, mo, ve, lr, t, clip_scale):
+        p, mo, ve = leaf(jax.lax.dynamic_index_in_dim(stack, i, keepdims=False),
+                         g, mo, ve, lr, t, clip_scale)
+        return jax.lax.dynamic_update_index_in_dim(stack, p, i, 0), mo, ve
+
+    return (jax.jit(leaf, donate_argnums=(0, 2, 3)),
+            jax.jit(one_layer, donate_argnums=(0, 3, 4)))
+
+
+@jax.jit
+def _norms(tree):
+    return [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
+            for x in jax.tree_util.tree_leaves(tree)]
 
 
 def leaf_norms(tree) -> List[float]:
-    fn = jax.jit(lambda t: [jnp.sqrt(jnp.sum(jnp.square(
-        x.astype(jnp.float32)))) for x in jax.tree_util.tree_leaves(t)])
-    return [float(x) for x in jax.device_get(fn(tree))]
+    return [float(x) for x in jax.device_get(_norms(tree))]
 
 
-def _gradient(prog: ref.Programs, m: ref.Dims, p, tokens, labels,
-              chosen, acc):
-    """(weighted loss, gradient) of the ``chosen`` (row, weight) pairs,
-    one row at a time, layer by layer, summed into ``acc`` (zeros)."""
-    loss = 0.0
-    for r, w in chosen:
-        t = jnp.asarray(tokens[r])
-        xs = prog.forward(p, t)
-        row_loss, dx, acc = prog.head(p, xs, jnp.asarray(labels[r]),
-                                      jnp.float32(w), acc)
-        for i in reversed(range(m.layers)):
-            dx, acc = prog.layer_back(p, np.int32(i), xs, dx, acc)
-        acc = prog.embed_back(t, dx, acc)
+def _zeros(tree, drop: int = 0):
+    """Float32 zeros of each leaf's shape, less its first ``drop`` axes."""
+    return jax.tree_util.tree_map(
+        lambda w: jnp.zeros(w.shape[drop:], jnp.float32), tree)
+
+
+def _gradient(prog: ref.Programs, m: ref.Dims, p, tokens, labels, chosen):
+    """(weighted loss, gradient) of the ``chosen`` (row, weight) pairs; the
+    gradient a tree of the weights' structure, in float32 on the host.
+
+    The rows' forwards run first, each keeping its layer inputs; then each
+    row's head; then each layer from the last, over every row in turn; then
+    the embedding. A leaf's gradient is summed over the rows in their order,
+    starting from zeros, as one row at a time would sum it (a tied head's
+    share and each row's embedding rows alternate, row by row). The chip
+    holds one part of the gradient at a time: the final norm's with an
+    untied head's, one layer's, the embedding's."""
+    rows = [(jnp.asarray(tokens[r]), jnp.asarray(labels[r]), jnp.float32(w))
+            for r, w in chosen]
+    xs = [prog.forward(p, t) for t, _, _ in rows]
+    part = {"final_norm": _zeros(p["final_norm"])}
+    if not m.tied:
+        part["lm_head"] = _zeros(p["lm_head"])
+    loss, dxs = 0.0, []
+    for (_, lab, w), x in zip(rows, xs):
+        row_loss, dx, part = prog.head(p, x, lab, w, part)
         loss += float(row_loss)
-        del xs, dx
-    return loss, acc
+        dxs.append(dx)
+    grad = jax.device_get(part)
+    grad["blocks"] = jax.tree_util.tree_map(
+        lambda w: np.empty(w.shape, np.float32), p["blocks"])
+    host_layers = jax.tree_util.tree_leaves(grad["blocks"])
+    done = []           # a layer's gradient, copied out while the next runs
+    for i in reversed(range(m.layers)):
+        acc = _zeros(p["blocks"], drop=1)
+        for j, x in enumerate(xs):
+            dxs[j], acc = prog.layer_back(p, np.int32(i), x, dxs[j], acc)
+        _to_host(done)
+        done = [(host, i, dev) for host, dev in
+                zip(host_layers, jax.tree_util.tree_leaves(acc))]
+        for _, _, dev in done:
+            dev.copy_to_host_async()
+        del acc
+    _to_host(done)
+    part = {"embed": _zeros(p["embed"])}
+    for (t, lab, w), x, dx in zip(rows, xs, dxs):
+        if m.tied:
+            part = prog.head(p, x, lab, w, part)[2]
+        part = prog.embed_back(t, dx, part)
+    grad["embed"] = jax.device_get(part["embed"])
+    del xs, dxs, part
+    return loss, grad
+
+
+def _to_host(copies: list) -> None:
+    """``host[at] = dev`` for each (host, at, dev), then forget them; a
+    ``None`` host only waits for ``dev``."""
+    for host, at, dev in copies:
+        if host is None:
+            dev.block_until_ready()
+        else:
+            host[at] = np.asarray(dev)
+    copies.clear()
+
+
+def _update(adamw, leaves: List[Any], stacked: List[bool],
+            grad: List[np.ndarray], mo: List[Optional[np.ndarray]],
+            ve: List[Optional[np.ndarray]], lr, t, scale,
+            keep: bool) -> None:
+    """AdamW on the device weights ``leaves``, one leaf, or one layer of a
+    stacked leaf, at a time: its gradient and moments moved in from the
+    host (zeros on the first step), and the moments moved back when
+    ``keep``. One slice runs while the last one's moments are copied out;
+    no more are queued, so the chip holds two slices' state at most."""
+    whole, one_layer = adamw
+    done: list = []
+    for j, g in enumerate(grad):
+        first = mo[j] is None
+        if keep and first:
+            mo[j], ve[j] = np.empty(g.shape, np.float32), \
+                np.empty(g.shape, np.float32)
+        for i in range(g.shape[0]) if stacked[j] else [None]:
+            at = slice(None) if i is None else i
+            gi = g[at]
+            if first:
+                m0 = jnp.zeros(gi.shape, jnp.float32)
+                v0 = jnp.zeros(gi.shape, jnp.float32)
+            else:
+                m0, v0 = mo[j][at], ve[j][at]
+            if i is None:
+                leaves[j], m1, v1 = whole(leaves[j], gi, m0, v0, lr, t, scale)
+            else:
+                leaves[j], m1, v1 = one_layer(leaves[j], np.int32(i), gi, m0,
+                                              v0, lr, t, scale)
+            _to_host(done)
+            done = [(mo[j] if keep else None, at, m1),
+                    (ve[j] if keep else None, at, v1)]
+            if keep:
+                m1.copy_to_host_async()
+                v1.copy_to_host_async()
+            del m1, v1
+    _to_host(done)
 
 
 def steps(config: Dict[str, Any], traffic: Dict[str, Any], batch: int,
@@ -157,10 +258,12 @@ def steps(config: Dict[str, Any], traffic: Dict[str, Any], batch: int,
     init = jax.jit(lambda k: ref.init(m, k))
     key = jax.random.PRNGKey(seed)
     p = init(key)
+    p0 = jax.device_get(p)          # the initial weights, kept on the host
     names = leaf_names(p)
-    moments = jax.jit(lambda t: jax.tree_util.tree_map(
-        lambda x: jnp.zeros(x.shape, jnp.float32), t))
-    mo, ve = moments(p), moments(p)
+    leaves, treedef = jax.tree_util.tree_flatten(p)
+    stacked = [n_.split("/")[0] == "blocks" for n_ in names]
+    mo: List[Optional[np.ndarray]] = [None] * len(names)
+    ve: List[Optional[np.ndarray]] = [None] * len(names)
     adamw = _adamw(opt)
     out: Dict[str, Any] = {"losses": [], "select": [], "decided": [],
                            "margins": [], "shortfalls": []}
@@ -197,9 +300,10 @@ def steps(config: Dict[str, Any], traffic: Dict[str, Any], batch: int,
         # train on the kept rows, weighted by their rank
         fr = int(kept["rank"])
         chosen = [(int(r), 1.0 / fr) for r in kept["pivots"][:fr]]
-        loss, g = _gradient(prog, m, p, tokens, labels, chosen, moments(p))
+        loss, g = _gradient(prog, m, p, tokens, labels, chosen)
         out["losses"].append(loss)
-        norms = leaf_norms(g)
+        g = jax.tree_util.tree_leaves(g)
+        norms = [leaf_norms([x])[0] for x in g]      # one leaf at a time
         gnorm = math.sqrt(sum(v * v for v in norms))
         clip = opt["clip_norm"]
         scale = min(1.0, clip / (gnorm + 1e-9)) if clip else 1.0
@@ -207,16 +311,17 @@ def steps(config: Dict[str, Any], traffic: Dict[str, Any], batch: int,
             out["grad_norms"] = {n_: v * scale for n_, v in zip(names, norms)}
         if fault != "unchanged":
             lr = learning_rate(opt, traffic["train_steps"], t)
-            p, mo, ve = adamw(p, g, mo, ve, jnp.float32(lr),
-                              jnp.float32(t + 1), jnp.float32(scale))
+            del p
+            _update(adamw, leaves, stacked, g, mo, ve, jnp.float32(lr),
+                    jnp.float32(t + 1), jnp.float32(scale),
+                    keep=t + 1 < n_steps)
+            p = jax.tree_util.tree_unflatten(treedef, leaves)
         del g
     if fault == "unchanged":
         out["grad_norms"] = {n_: 0.0 for n_ in names}
     del mo, ve
-    p0 = init(key)
-    diff = jax.jit(lambda a, b: [jnp.sqrt(jnp.sum(jnp.square(
-        x.astype(jnp.float32) - y.astype(jnp.float32)))) for x, y in zip(
-            jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b))])
-    out["change_norms"] = {n_: float(v) for n_, v in
-                           zip(names, jax.device_get(diff(p, p0)))}
+    diff = jax.jit(lambda x, y: jnp.sqrt(jnp.sum(jnp.square(
+        x.astype(jnp.float32) - y.astype(jnp.float32)))))
+    out["change_norms"] = {n_: float(diff(w, w0)) for n_, w, w0 in zip(
+        names, leaves, jax.tree_util.tree_leaves(p0))}
     return out
