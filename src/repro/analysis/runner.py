@@ -181,12 +181,16 @@ def check_attention() -> Report:
 def check_vmem() -> Report:
     """The production kernel configurations + headroom notes (the
     blockwise-KV groundwork: how far T can grow before flash must tile)."""
+    from repro.models import layers as layers_lib
+
     report = Report()
     # probe model shape, and a TPU-production shape for the headroom note
     report.extend(vmem.flash_attention_report(
-        S=_ATTN_S, T=_ATTN_S, head_dim=16, block_q=64, block_k=64))
+        S=_ATTN_S, T=_ATTN_S, head_dim=16, block_q=64, block_k=64,
+        itemsize=4))
+    (fbq, fbk), _ = layers_lib._flash_blocks(2048, 2048, 64, 2)
     report.extend(vmem.flash_attention_report(
-        S=2048, T=2048, head_dim=64, block_q=128, block_k=128))
+        S=2048, T=2048, head_dim=64, block_q=fbq, block_k=fbk))
     report.extend(vmem.fused_select_vmem(
         _SEL_K, _SEL_R, _SEL_D, _SEL_R).report())
     report.extend(vmem.fast_maxvol_vmem(1024, 64).report())

@@ -88,19 +88,24 @@ def check_divisible(extent: int, block: int, axis: str,
 # ---------------------------------------------------------------------------
 
 def flash_forward_vmem(T: int, head_dim: int, block_q: int,
-                       itemsize: int = 4) -> VmemEstimate:
-    """Flash-attention forward, one grid program: the full K and V streams
-    (kv BlockSpec ``(1, T, Dh)``) plus 3 q-sized tiles (q block, acc block,
-    out block), matching the wrapper guard
-    ``(2*T*Dh + 3*block_q*Dh) * 4 <= budget``."""
+                       block_k: int = 128, itemsize: int = 2) -> VmemEstimate:
+    """Flash-attention forward, one grid program: the whole K and V streams
+    (kv BlockSpec ``(1, T, Dh)``) and the q and o tiles at the operands'
+    own itemsize, each double-buffered by the pipeline; the f32 score and
+    p tiles with p's two halves at that itemsize; the f32 (block_q, 1)
+    m and l, each padded to 128 lanes; the f32 accumulator."""
     return VmemEstimate(
-        kernel=f"flash_attention(T={T}, Dh={head_dim}, bq={block_q})",
-        parts={"kv_stream": 2 * T * head_dim * itemsize,
-               "q_tiles": 3 * block_q * head_dim * itemsize})
+        kernel=(f"flash_attention(T={T}, Dh={head_dim}, bq={block_q}, "
+                f"bk={block_k})"),
+        parts={"kv_stream": 2 * 2 * T * head_dim * itemsize,
+               "q_o_tiles": 2 * 2 * block_q * head_dim * itemsize,
+               "score_tiles": block_q * block_k * (2 * 4 + 2 * itemsize),
+               "stats": 2 * block_q * 128 * 4,
+               "acc": block_q * head_dim * 4})
 
 
-def flash_attention_report(S: int, T: int, head_dim: int,
-                           block_q: int, block_k: int) -> Report:
+def flash_attention_report(S: int, T: int, head_dim: int, block_q: int,
+                           block_k: int, itemsize: int = 2) -> Report:
     """Full feasibility check for one flash shape: divisibility + VMEM."""
     rep = Report()
     loc = f"flash_attention(S={S}, T={T}, Dh={head_dim})"
@@ -108,14 +113,16 @@ def flash_attention_report(S: int, T: int, head_dim: int,
         f = check_divisible(extent, block, axis, loc)
         if f:
             rep.add(f)
-    rep.extend(flash_forward_vmem(T, head_dim, block_q).report(loc))
+    rep.extend(flash_forward_vmem(T, head_dim, block_q, block_k,
+                                  itemsize).report(loc))
     return rep
 
 
-def flash_feasible(S: int, T: int, head_dim: int,
-                   block_q: int, block_k: int) -> bool:
+def flash_feasible(S: int, T: int, head_dim: int, block_q: int,
+                   block_k: int, itemsize: int = 2) -> bool:
     """The router's go/no-go: blocks divide AND the footprint fits."""
-    return flash_attention_report(S, T, head_dim, block_q, block_k).ok
+    return flash_attention_report(S, T, head_dim, block_q, block_k,
+                                  itemsize).ok
 
 
 def fused_select_vmem(K: int, R: int, d: int, rank: int,

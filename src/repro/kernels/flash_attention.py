@@ -7,7 +7,16 @@ online-softmax schedule but explicit VMEM residency:
   grid = (BH, Sq // BLOCK_Q): each program owns one query tile. K/V for the
   (b,h) stream stay VMEM-resident across the program's KV loop (budget-
   guarded by the wrapper); scores exist only as a (BLOCK_Q, BLOCK_K) tile in
-  registers/VMEM. m/l/acc run in f32 for numerical parity with the oracle.
+  registers/VMEM.
+
+Forward: q·kᵀ and p·v run on the tiles as stored (bf16 on the chip) with
+f32 accumulation; q·kᵀ products of bf16 values are exact in f32, and p
+enters p·v as two terms of v's type (hi + lo, about 16 significant bits).
+m and l are f32 (BLOCK_Q, 1) columns, so no tile relayouts them (a
+lane-replicated (BLOCK_Q, 128) runs no faster on a v5e); acc is f32. Only
+the KV tiles that cross the causal diagonal or the window's edge build the
+mask. The forward takes its own, larger tiles
+(``models/layers.py:_flash_blocks``); the backward kernels keep theirs.
 
 GQA folds into the grid: q streams are (B·H) while k/v stay (B·Hkv); the
 k/v BlockSpec index map divides the stream id by ``group`` so no repeated
@@ -39,7 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Mask fill value. A tile whose mask is ALL false leaves the running max at
 # this sentinel; the online-softmax update must then suppress its
-# contribution entirely (p = 0), not exp(0) = 1 — see _tile_probs.
+# contribution entirely (p = 0), not exp(0) = 1 — see _flash_kernel.
 _MASK = -1e30
 _MASK_GUARD = -0.5e30
 
@@ -83,46 +92,87 @@ def _kv_bounds(q_offset, BQ: int, block_k: int, n_tiles: int, causal: bool,
     return lo, hi
 
 
+def _pv(p, v) -> jax.Array:
+    """p·v accumulated in f32 at about 16 significant bits of p: a v in a
+    narrower type takes p as two terms of that type, hi + lo, so the MXU
+    runs at v's own operand type and p loses only what the two drop."""
+    if v.dtype == jnp.float32:
+        return jnp.dot(p, v, preferred_element_type=jnp.float32)
+    p_hi = p.astype(v.dtype)
+    p_lo = (p - p_hi.astype(jnp.float32)).astype(v.dtype)
+    return (jnp.dot(p_hi, v, preferred_element_type=jnp.float32)
+            + jnp.dot(p_lo, v, preferred_element_type=jnp.float32))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref, *, block_k: int,
                   causal: bool, use_window: bool, softcap: Optional[float],
                   scale: float, bound_loop: bool):
     """Blocks: q (1, BQ, Dh); k/v (1, T, Dh); w (1,) in SMEM;
-    o (1, BQ, Dh); lse (1, 1, BQ) f32."""
-    qs = q_ref[0].astype(jnp.float32) * scale          # (BQ, Dh)
-    BQ = qs.shape[0]
+    o (1, BQ, Dh); lse (1, 1, BQ) f32.
+
+    q·kᵀ and p·v take the tiles in their stored type with f32
+    accumulation. Only the KV tiles that cross the causal diagonal or the
+    window's edge build and apply the mask; the tiles between them are
+    seen whole by every row of the q tile."""
+    q = q_ref[0]                                       # (BQ, Dh)
+    BQ, Dh = q.shape
     T = k_ref.shape[1]
     q_offset = pl.program_id(1) * BQ
     w = w_ref[0]
 
-    m0 = jnp.full((BQ,), _MASK, jnp.float32)
-    l0 = jnp.zeros((BQ,), jnp.float32)
-    acc0 = jnp.zeros_like(qs)
-
-    def body(i, carry):
+    def tile(i, carry, masked: bool):
         m, l, acc = carry
-        k = k_ref[0, pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
-        mask = _tile_mask(q_offset, i * block_k, BQ, block_k,
-                          causal, use_window, w)
-        s = _tile_scores(qs, k, mask, softcap)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        start = pl.multiple_of(i * block_k, block_k)
+        k = k_ref[0, pl.dslice(start, block_k), :]
+        v = v_ref[0, pl.dslice(start, block_k), :]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if scale != 1.0:
+            s = s * scale
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        if masked:
+            s = jnp.where(_tile_mask(q_offset, start, BQ, block_k, causal,
+                                     use_window, w), s, _MASK)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        # A fully-masked row keeps m_new at the _MASK sentinel; without the
-        # guard p = exp(s - m_new) = exp(0) = 1 there, silently averaging V.
-        p = jnp.where(m_new[:, None] > _MASK_GUARD,
-                      jnp.exp(s - m_new[:, None]), 0.0)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + p @ v
+        p = jnp.exp(s - m_new)
+        if masked and use_window:
+            # Only a window can mask a row's whole horizon so far: its m_new
+            # stays at the _MASK sentinel, and without the guard p = exp(0)
+            # = 1 there, silently averaging V.
+            p = jnp.where(m_new > _MASK_GUARD, p, 0.0)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + _pv(p, v)
         return m_new, l, acc
 
+    masked = functools.partial(tile, masked=True)
+    whole = functools.partial(tile, masked=False)
     lo, hi = _kv_bounds(q_offset, BQ, block_k, T // block_k,
                         causal, use_window, w, bound_loop)
-    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    # [first, last): tiles every row of this q tile sees whole — past the
+    # window's edge for the tile's last row, at or below the diagonal for
+    # its first. Tiles wholly outside the mask (bound_loop=False) stay on
+    # the masked path, where they add exactly nothing.
+    first, last = lo, hi
+    if use_window:
+        first = jnp.clip((q_offset + BQ - 1 - w) // block_k + 1, lo, hi)
+    if causal:
+        last = jnp.clip((q_offset + 1) // block_k, first, hi)
+    carry = (jnp.full((BQ, 1), _MASK, jnp.float32),
+             jnp.zeros((BQ, 1), jnp.float32),
+             jnp.zeros((BQ, Dh), jnp.float32))
+    if use_window:
+        carry = jax.lax.fori_loop(lo, first, masked, carry)
+    carry = jax.lax.fori_loop(first, last, whole, carry)
+    if causal:
+        carry = jax.lax.fori_loop(last, hi, masked, carry)
+    m, l, acc = carry
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     # logsumexp residual for the backward recompute; +inf marks rows whose
     # whole horizon is masked (output 0), so bwd p = exp(s - lse) = 0 there.
     lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
-    lse_ref[0] = lse[None, :]
+    lse_ref[0] = lse.T
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, w_ref,
@@ -290,27 +340,31 @@ def _backward(q, k, v, w, o, lse, do, *, block_q, block_k, causal, use_window,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_fn(block_q: int, block_k: int, causal: bool, use_window: bool,
-                   softcap: Optional[float], scale: float, group: int,
-                   bound_loop: bool, interpret: bool):
-    """custom_vjp flash attention for one static config. The sliding window
-    ``w`` is a (1,) int32 PRIMAL (it may be traced — gemma2's scanned
-    is_local); its cotangent is float0."""
-    opts = {"block_q": block_q, "block_k": block_k, "causal": causal,
-            "use_window": use_window, "softcap": softcap, "scale": scale,
-            "group": group, "bound_loop": bound_loop, "interpret": interpret}
+def _make_flash_fn(fwd_blocks: Tuple[int, int], bwd_blocks: Tuple[int, int],
+                   causal: bool, use_window: bool, softcap: Optional[float],
+                   scale: float, group: int, bound_loop: bool,
+                   interpret: bool):
+    """custom_vjp flash attention for one static config; the forward and
+    the backward kernels take their own (block_q, block_k) tiles. The
+    sliding window ``w`` is a (1,) int32 PRIMAL (it may be traced —
+    gemma2's scanned is_local); its cotangent is float0."""
+    opts = {"causal": causal, "use_window": use_window, "softcap": softcap,
+            "scale": scale, "group": group, "bound_loop": bound_loop,
+            "interpret": interpret}
+    fwd = dict(opts, block_q=fwd_blocks[0], block_k=fwd_blocks[1])
+    bwd = dict(opts, block_q=bwd_blocks[0], block_k=bwd_blocks[1])
 
     @jax.custom_vjp
     def fa(q, k, v, w):
-        return _forward(q, k, v, w, **opts)[0]
+        return _forward(q, k, v, w, **fwd)[0]
 
     def fa_fwd(q, k, v, w):
-        o, lse = _forward(q, k, v, w, **opts)
+        o, lse = _forward(q, k, v, w, **fwd)
         return o, (q, k, v, w, o, lse)
 
     def fa_bwd(res, do):
         q, k, v, w, o, lse = res
-        dq, dk, dv = _backward(q, k, v, w, o, lse, do, **opts)
+        dq, dk, dv = _backward(q, k, v, w, o, lse, do, **bwd)
         return dq, dk, dv, np.zeros((1,), jax.dtypes.float0)
 
     fa.defvjp(fa_fwd, fa_bwd)
@@ -325,24 +379,31 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            interpret: bool = False,
                            group: int = 1,
                            scale: Optional[float] = None,
-                           bound_loop: bool = True) -> jax.Array:
+                           bound_loop: bool = True,
+                           fwd_block_q: Optional[int] = None,
+                           fwd_block_k: Optional[int] = None) -> jax.Array:
     """q: (B·H, Sq, Dh); k/v: (B·Hkv, T, Dh) with H = Hkv·group (streams
     ordered head-major so q stream i reads kv stream i // group). Returns
     (B·H, Sq, Dh) in q dtype. Differentiable (custom_vjp with recompute).
 
+    ``block_q``/``block_k`` tile the backward kernels, and the forward
+    unless ``fwd_block_q``/``fwd_block_k`` say otherwise.
     ``scale`` defaults to 1/sqrt(Dh); pass 1.0 for pre-scaled queries.
     ``window`` may be a python int or a traced int scalar (dynamic per-layer
-    sliding window); values ≥ T are a no-op. VMEM per program: 2·T·Dh f32
-    (K,V) + 3 q-tiles ⇒ guard at ~12 MB.
+    sliding window); values ≥ T are a no-op. The forward's VMEM per program
+    (``analysis/vmem.py:flash_forward_vmem``) is guarded at 12 MB.
     """
     BH, Sq, Dh = q.shape
     BHkv, T = k.shape[0], k.shape[1]
+    fbq = block_q if fwd_block_q is None else fwd_block_q
+    fbk = block_k if fwd_block_k is None else fwd_block_k
     if BHkv * group != BH or v.shape != k.shape:
         raise ValueError(f"GQA shapes: q {q.shape}, k {k.shape}, group={group}")
-    if Sq % block_q or T % block_k:
-        raise ValueError(f"Sq={Sq} % {block_q} or T={T} % {block_k} != 0")
+    for bq, bk in ((block_q, block_k), (fbq, fbk)):
+        if Sq % bq or T % bk:
+            raise ValueError(f"Sq={Sq} % {bq} or T={T} % {bk} != 0")
     from repro.analysis.vmem import flash_forward_vmem
-    est = flash_forward_vmem(T, Dh, block_q)
+    est = flash_forward_vmem(T, Dh, fbq, fbk, k.dtype.itemsize)
     if not est.fits:
         raise ValueError(
             f"KV stream exceeds the single-program VMEM budget "
@@ -355,7 +416,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         w = jnp.full((1,), T, jnp.int32)
     else:
         w = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
-    fa = _make_flash_fn(block_q, block_k, bool(causal), use_window,
+    fa = _make_flash_fn((int(fbq), int(fbk)), (int(block_q), int(block_k)),
+                        bool(causal), use_window,
                         None if softcap is None else float(softcap),
                         float(scale), int(group), bool(bound_loop),
                         bool(interpret))

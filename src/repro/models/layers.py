@@ -53,24 +53,35 @@ def _activation(name: str):
 # attention
 # ---------------------------------------------------------------------------
 
+# The backward kernels' tiles (flash_dq, flash_dkv): the largest of these
+# dividing each length. The forward's tiles below 128 too.
 _FLASH_BLOCKS = (128, 64, 32, 16, 8)
+# The forward's (q tile, K tile), first that divides the lengths and fits
+# the VMEM budget; larger tiles pay once the loop body runs at the MXU's
+# operand type, a long K tile most (swept on a v5e at 576 × 2048 × 64:
+# 256 × 512 ran 2.7× faster than 128 × 128; PERF.md §6).
+_FLASH_FWD_BLOCKS = ((256, 512), (256, 256), (128, 128))
 
 
-def _flash_blocks(S: int, T: int):
-    """Largest kernel tile sizes dividing the q/kv lengths (None = none fit)."""
+def _flash_blocks(S: int, T: int, head_dim: int, itemsize: int):
+    """((fwd_q, fwd_k), (bwd_q, bwd_k)) kernel tiles for q/kv lengths S/T,
+    or None where no tile divides them or no forward fits VMEM — the SAME
+    estimator the kernel wrapper enforces (repro.analysis.vmem), so the
+    shape the router plans with is the shape the kernel accepts."""
+    from repro.analysis.vmem import flash_forward_vmem
     bq = next((b for b in _FLASH_BLOCKS if S % b == 0), None)
     bk = next((b for b in _FLASH_BLOCKS if T % b == 0), None)
-    return bq, bk
+    if bq is None or bk is None:
+        return None
+    fwd = next((
+        (fq, fk) for fq, fk in _FLASH_FWD_BLOCKS + ((bq, bk),)
+        if S % fq == 0 and T % fk == 0
+        and flash_forward_vmem(T, head_dim, fq, fk, itemsize).fits), None)
+    return None if fwd is None else (fwd, (bq, bk))
 
 
 def _flash_feasible(cfg, S: int, T: int) -> bool:
-    bq, bk = _flash_blocks(S, T)
-    if bq is None or bk is None:
-        return False
-    # the SAME estimator the kernel wrapper enforces (repro.analysis.vmem):
-    # the shape the router plans with is the shape the kernel accepts
-    from repro.analysis.vmem import flash_forward_vmem
-    return flash_forward_vmem(T, cfg.head_dim, bq).fits
+    return _flash_blocks(S, T, cfg.head_dim, cfg.dtype.itemsize) is not None
 
 
 def resolve_attn_backend(cfg, S: int, T: int) -> str:
@@ -107,7 +118,8 @@ def _flash_attention(cfg, q: jax.Array, k: jax.Array, v: jax.Array,
     """
     from repro.kernels import flash_attention as _fa
     S = q.shape[1]
-    bq, bk = _flash_blocks(S, S)
+    (fbq, fbk), (bq, bk) = _flash_blocks(S, S, cfg.head_dim,
+                                         cfg.dtype.itemsize)
     if cfg.sliding_window is None:
         window = None
     elif isinstance(is_local, bool):
@@ -124,7 +136,8 @@ def _flash_attention(cfg, q: jax.Array, k: jax.Array, v: jax.Array,
         kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
         vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
         out = _fa.flash_attention_pallas(
-            qf, kf, vf, block_q=bq, block_k=bk, causal=True,
+            qf, kf, vf, block_q=bq, block_k=bk, fwd_block_q=fbq,
+            fwd_block_k=fbk, causal=True,
             window=w[0] if w else None,
             softcap=cfg.attn_logit_softcap, group=H // Hkv, scale=1.0,
             interpret=jax.default_backend() != "tpu")

@@ -208,10 +208,13 @@ def test_vmem_divisibility_flagged():
 
 
 def test_vmem_formulas_match_kernel_guards():
-    # flash: the wrapper guard formula, bit-exact
-    T, Dh, bq = 512, 64, 128
-    assert vmem.flash_forward_vmem(T, Dh, bq).total == \
-        (2 * T * Dh + 3 * bq * Dh) * 4
+    # flash forward: K/V and the q/o tiles at their itemsize, double-
+    # buffered; f32 s and p with p's two halves; f32 m, l (columns padded to 128 lanes), acc
+    T, Dh, bq, bk = 512, 64, 256, 128
+    for itemsize in (2, 4):
+        assert vmem.flash_forward_vmem(T, Dh, bq, bk, itemsize).total == (
+            4 * T * Dh * itemsize + 4 * bq * Dh * itemsize
+            + bq * bk * (8 + 2 * itemsize) + 2 * bq * 128 * 4 + bq * Dh * 4)
     # fused selection: graft_select._check_budget's word count, bit-exact
     K, R, d, rank = 256, 32, 1024, 16
     assert vmem.fused_select_vmem(K, R, d, rank).total == \
@@ -224,13 +227,17 @@ def test_vmem_feasible_agrees_with_attn_router():
 
     class Cfg:
         head_dim = 64
+        dtype = jnp.dtype(jnp.bfloat16)
 
-    for S, T in ((64, 64), (128, 4096), (128, 65536)):
-        bq, bk = layers_lib._flash_blocks(S, T)
+    # (2048, 2048) takes a forward tile larger than the backward's 128
+    for S, T in ((64, 64), (128, 4096), (128, 65536), (2048, 2048)):
+        blocks = layers_lib._flash_blocks(S, T, Cfg.head_dim, 2)
         expect = layers_lib._flash_feasible(Cfg, S, T)
-        got = (bq is not None and bk is not None and
-               vmem.flash_feasible(S, T, Cfg.head_dim, bq, bk))
+        got = blocks is not None and vmem.flash_feasible(
+            S, T, Cfg.head_dim, *blocks[0], itemsize=2)
         assert got == expect, (S, T)
+    (fbq, fbk), (bq, bk) = layers_lib._flash_blocks(2048, 2048, 64, 2)
+    assert (bq, bk) == (128, 128) and fbq > bq
 
 
 def test_vmem_headroom_reported():

@@ -24,11 +24,19 @@ from repro.kernels.graft_select import (fused_graft_select_batched_pallas,
                                         fused_graft_select_pallas)
 from repro.kernels.ops import compiled_kernel_names
 from repro.kernels.projection_sweep import projection_sweep_pallas
+from repro.models.layers import _flash_blocks
 
 # minicpm-2b: d_model 2304 (the probe grad-embedding width), 36 heads of
 # 64; a 64-example batch with r_max 32 and 2048-token sequences
 K, R, D, RANK = 64, 32, 2304, 32
-FLASH_SHAPE = (72, 2048, 64)            # (B·H, S, head_dim), B = 2
+# flash cases: (B·H, B·Hkv, S, head_dim), softcap, traced window. minicpm-2b's
+# selection forward over 16 rows (the benchmark cell's) and its 2-row
+# subset; gemma2-27b's GQA 32/16 heads of 128 with its softcap and the
+# scanned local/global window
+FLASH_FWD = {"minicpm-select": ((576, 576, 2048, 64), None, False),
+             "gemma2": ((32, 16, 2048, 128), 50.0, True)}
+FLASH_FWD_BWD = {"minicpm-subset": ((72, 72, 2048, 64), None, False),
+                 "gemma2": ((32, 16, 2048, 128), 50.0, True)}
 
 
 @pytest.fixture(scope="module")
@@ -87,21 +95,37 @@ def test_projection_sweep_compiles(one_chip):
                     "projection_sweep")
 
 
-def test_flash_forward_compiles(one_chip):
-    qkv = [jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16,
-                                sharding=one_chip)] * 3
-    compiled = _compile(
-        lambda q, k, v: flash_attention_pallas(q, k, v, scale=1.0), *qkv)
-    _assert_kernels(compiled, "flash_fwd")
+def _flash(shape, softcap, windowed, one_chip):
+    """The model path's call: the router's tiles, pre-scaled q, and for a
+    windowed case the window as a traced operand."""
+    bh, bhkv, S, Dh = shape
+    (fbq, fbk), (bq, bk) = _flash_blocks(S, S, Dh, 2)
+    bf16 = lambda n: jax.ShapeDtypeStruct((n, S, Dh), jnp.bfloat16,
+                                          sharding=one_chip)
+    shapes = [bf16(bh), bf16(bhkv), bf16(bhkv)]
+    if windowed:
+        shapes.append(jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+
+    def attn(q, k, v, *w):
+        return flash_attention_pallas(
+            q, k, v, block_q=bq, block_k=bk, fwd_block_q=fbq, fwd_block_k=fbk,
+            window=w[0] if w else None, softcap=softcap, group=bh // bhkv,
+            scale=1.0)
+    return attn, shapes
 
 
-def test_flash_forward_backward_compiles(one_chip):
-    qkv = [jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16,
-                                sharding=one_chip)] * 3
+@pytest.mark.parametrize("case", sorted(FLASH_FWD))
+def test_flash_forward_compiles(one_chip, case):
+    attn, shapes = _flash(*FLASH_FWD[case], one_chip)
+    _assert_kernels(_compile(attn, *shapes), "flash_fwd")
 
-    def loss(q, k, v):
-        o = flash_attention_pallas(q, k, v, scale=1.0)
-        return jnp.sum(o.astype(jnp.float32))
 
-    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+@pytest.mark.parametrize("case", sorted(FLASH_FWD_BWD))
+def test_flash_forward_backward_compiles(one_chip, case):
+    attn, shapes = _flash(*FLASH_FWD_BWD[case], one_chip)
+
+    def loss(q, k, v, *w):
+        return jnp.sum(attn(q, k, v, *w).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), *shapes)
     _assert_kernels(compiled, "flash_fwd", "flash_dq", "flash_dkv")

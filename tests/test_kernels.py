@@ -117,27 +117,54 @@ class TestOpsDispatch:
                                    float(s_p.last_error), atol=1e-5)
 
 
+def _qkv(rng, q_shape, kv_shape, dtype):
+    return tuple(jnp.asarray(rng.normal(size=s).astype(np.float32)).astype(dtype)
+                 for s in (q_shape, kv_shape, kv_shape))
+
+
+def _assert_matches_oracle(o, o_ref, dtype):
+    """f32 inputs: 2e-5. bf16 inputs, against the f32 oracle of the same
+    values: one bf16 ulp of each output (its rounding) plus 1e-5 for f32
+    accumulation and p's two bf16 terms; p as one bf16 term misses by 5e-4."""
+    if dtype == "float32":
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    else:
+        assert o.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(o, np.float32),
+                                   np.asarray(o_ref, np.float32),
+                                   rtol=2.0 ** -7, atol=1e-5)
+
+
 class TestFlashAttentionKernel:
-    @pytest.mark.parametrize("BH,S,Dh,bq,bk,causal,window,softcap", [
-        (2, 256, 64, 128, 128, True, None, None),
-        (1, 256, 128, 64, 64, True, None, 50.0),      # gemma2-style softcap
-        (3, 128, 32, 64, 32, True, 48, None),          # sliding window
-        (2, 256, 64, 128, 64, False, None, None),      # bidirectional
-        (1, 128, 64, 128, 128, True, None, None),      # single tile
-    ])
-    def test_matches_dense_oracle(self, rng, BH, S, Dh, bq, bk, causal,
-                                  window, softcap):
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize(
+        "BH,S,Dh,bq,bk,causal,window,softcap,group,fwd", [
+            (2, 256, 64, 128, 128, True, None, None, 1, None),
+            (1, 256, 128, 64, 64, True, None, 50.0, 1, None),  # gemma2 softcap
+            (3, 128, 32, 64, 32, True, 48, None, 1, None),     # sliding window
+            (2, 256, 64, 128, 64, False, None, None, 1, None),  # bidirectional
+            (1, 128, 64, 128, 128, True, None, None, 1, None),  # single tile
+            # GQA, with forward q tiles larger than their K tiles; a window
+            # wide enough that tiles between its edge and the diagonal
+            # take the unmasked path
+            (2, 256, 64, 64, 64, True, 160, None, 2, (64, 32)),
+            (1, 256, 128, 64, 64, True, 96, 30.0, 4, (256, 32)),
+        ])
+    def test_matches_dense_oracle(self, rng, dtype, BH, S, Dh, bq, bk, causal,
+                                  window, softcap, group, fwd):
         from repro.kernels.flash_attention import flash_attention_pallas
-        q = jnp.asarray(rng.normal(size=(BH, S, Dh)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(BH, S, Dh)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(BH, S, Dh)).astype(np.float32))
+        q, k, v = _qkv(rng, (BH * group, S, Dh), (BH, S, Dh), dtype)
+        fbq, fbk = fwd or (None, None)
         o_k = flash_attention_pallas(q, k, v, block_q=bq, block_k=bk,
                                      causal=causal, window=window,
-                                     softcap=softcap, interpret=True)
-        o_r = ref.flash_attention_ref(q, k, v, causal=causal, window=window,
+                                     softcap=softcap, group=group,
+                                     fwd_block_q=fbq, fwd_block_k=fbk,
+                                     interpret=True)
+        f32 = lambda x: jnp.repeat(x.astype(jnp.float32), group, axis=0)
+        o_r = ref.flash_attention_ref(q.astype(jnp.float32), f32(k), f32(v),
+                                      causal=causal, window=window,
                                       softcap=softcap)
-        np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
-                                   atol=2e-5)
+        _assert_matches_oracle(o_k, o_r, dtype)
 
     def test_block_size_invariance(self, rng):
         from repro.kernels.flash_attention import flash_attention_pallas
@@ -158,42 +185,46 @@ class TestFlashAttentionKernel:
 
     def test_bf16_inputs(self, rng):
         from repro.kernels.flash_attention import flash_attention_pallas
-        q = jnp.asarray(rng.normal(size=(1, 128, 64))).astype(jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(1, 128, 64))).astype(jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(1, 128, 64))).astype(jnp.bfloat16)
+        q, k, v = _qkv(rng, (1, 128, 64), (1, 128, 64), jnp.bfloat16)
         o_k = flash_attention_pallas(q, k, v, block_q=64, block_k=64,
                                      interpret=True)
-        o_r = ref.flash_attention_ref(q, k, v)
-        assert o_k.dtype == jnp.bfloat16
-        np.testing.assert_allclose(np.asarray(o_k, np.float32),
-                                   np.asarray(o_r, np.float32), atol=3e-2)
+        o_r = ref.flash_attention_ref(*(x.astype(jnp.float32)
+                                        for x in (q, k, v)))
+        _assert_matches_oracle(o_k, o_r, "bfloat16")
 
-    def test_fully_masked_rows_are_zero(self, rng):
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("fwd", [None, (128, 32)])
+    def test_fully_masked_rows_are_zero(self, rng, dtype, fwd):
         """Regression for the masked-tile bug: a window that masks EVERY
         key for a q row must yield exactly 0, not exp(-1e30 − (−1e30)) = 1
         renormalized into the mean of V (the pre-fix garbage)."""
         from repro.kernels.flash_attention import flash_attention_pallas
-        q = jnp.asarray(rng.normal(size=(2, 128, 32)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(2, 128, 32)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(2, 128, 32)).astype(np.float32))
+        q, k, v = _qkv(rng, (2, 128, 32), (2, 128, 32), dtype)
+        fbq, fbk = fwd or (None, None)
         o = flash_attention_pallas(q, k, v, block_q=64, block_k=64,
-                                   causal=True, window=0, interpret=True)
-        assert np.array_equal(np.asarray(o), np.zeros_like(np.asarray(o)))
+                                   causal=True, window=0, fwd_block_q=fbq,
+                                   fwd_block_k=fbk, interpret=True)
+        assert np.array_equal(np.asarray(o, np.float32),
+                              np.zeros(o.shape, np.float32))
 
-    @pytest.mark.parametrize("causal,window", [(True, None), (True, 48)])
-    def test_bounded_loop_bit_parity(self, rng, causal, window):
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("fwd", [None, (128, 32)])
+    @pytest.mark.parametrize("causal,window", [
+        (True, None), (True, 48), (True, 160)])
+    def test_bounded_loop_bit_parity(self, rng, causal, window, fwd, dtype):
         """The causal/window KV loop bound must be a pure skip: every tile
         it skips is fully masked, so bounded vs exhaustive is BITWISE
         identical (skipped tiles contribute alpha=1, p=0 exactly)."""
         from repro.kernels.flash_attention import flash_attention_pallas
-        q = jnp.asarray(rng.normal(size=(2, 256, 32)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(2, 256, 32)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(2, 256, 32)).astype(np.float32))
+        q, k, v = _qkv(rng, (2, 256, 32), (2, 256, 32), dtype)
+        fbq, fbk = fwd or (None, None)
         kw = {"block_q": 64, "block_k": 64, "causal": causal,
-              "window": window, "interpret": True}
+              "window": window, "fwd_block_q": fbq, "fwd_block_k": fbk,
+              "interpret": True}
         o_b = flash_attention_pallas(q, k, v, bound_loop=True, **kw)
         o_u = flash_attention_pallas(q, k, v, bound_loop=False, **kw)
-        assert np.array_equal(np.asarray(o_b), np.asarray(o_u))
+        assert np.array_equal(np.asarray(o_b, np.float32),
+                              np.asarray(o_u, np.float32))
 
     @pytest.mark.parametrize("group", [2, 4])
     def test_gqa_matches_repeated_kv(self, rng, group):
@@ -212,10 +243,11 @@ class TestFlashAttentionKernel:
         np.testing.assert_allclose(np.asarray(o_g), np.asarray(o_r),
                                    atol=2e-5)
 
+    @pytest.mark.parametrize("fwd", [None, (128, 32)])
     @pytest.mark.parametrize("window,softcap,group", [
         (None, None, 1), (48, None, 1), (None, 30.0, 1), (48, 30.0, 2),
     ])
-    def test_grad_matches_ref(self, rng, window, softcap, group):
+    def test_grad_matches_ref(self, rng, window, softcap, group, fwd):
         """custom_vjp backward (recompute dq/dk/dv kernels) vs autodiff
         through the dense oracle."""
         from repro.kernels.flash_attention import flash_attention_pallas
@@ -227,10 +259,13 @@ class TestFlashAttentionKernel:
         dout = jnp.asarray(
             rng.normal(size=(BHkv * group, S, Dh)).astype(np.float32))
 
+        fbq, fbk = fwd or (None, None)
+
         def loss_k(q, k, v):
             o = flash_attention_pallas(q, k, v, block_q=64, block_k=64,
                                        window=window, softcap=softcap,
-                                       group=group, interpret=True)
+                                       group=group, fwd_block_q=fbq,
+                                       fwd_block_k=fbk, interpret=True)
             return jnp.sum(o * dout)
 
         def loss_r(q, k, v):
